@@ -43,6 +43,7 @@ import numpy as np
 
 from bench_io import BenchBundle
 from repro.kernels import ops, ref
+from repro.launch.compile_cache import use_compile_cache
 
 FAST = bool(int(os.environ.get("REPRO_BENCH_FAST", "0")))
 
@@ -375,6 +376,7 @@ def _bundle(rows: list[str]) -> BenchBundle:
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     all_rows = run()
     for r in all_rows:
         print(r)

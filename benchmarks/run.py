@@ -158,6 +158,9 @@ BENCHES = {
 
 
 def main() -> None:
+    from repro.launch.compile_cache import use_compile_cache
+
+    use_compile_cache()
     names = [a for a in sys.argv[1:] if a in BENCHES] or list(BENCHES)
     print("name,us_per_call,derived")
     failures = 0

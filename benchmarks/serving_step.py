@@ -64,6 +64,7 @@ from bench_io import BenchBundle
 from repro.configs import get_smoke_config
 from repro.core import LayerCost, build_cost_profile
 from repro.core.multitier import TierSpec, expected_time_multitier, solve_multitier
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import model as M
 from repro.serving import (
     FlapWindow,
@@ -633,6 +634,7 @@ def part5_faults(cfg0, params, bundle):
 
 
 def main() -> None:
+    use_compile_cache()
     cfg = dataclasses.replace(
         get_smoke_config("qwen3_8b"), num_layers=4, branch_layers=(1, 3)
     )

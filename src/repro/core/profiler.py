@@ -103,10 +103,7 @@ def output_bytes(tree) -> float:
 def _cost_analysis(fn: Callable, *abstract_args) -> dict:
     lowered = jax.jit(fn).lower(*abstract_args)
     compiled = lowered.compile()
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):  # older jax returns [dict]
-        ca = ca[0]
-    return ca or {}
+    return compiled.cost_analysis() or {}
 
 
 def analyze_layer_costs(

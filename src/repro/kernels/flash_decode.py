@@ -17,10 +17,16 @@ block index maps read it before the body runs and DMA only the survivor
 rows of the full-batch cache — a dense sub-batch attends in-place against
 the resident cache with zero gather copies.
 
-Grid: (B, K, C_tiles) — the cache dim is the sequential inner loop; each
-(batch, kv-head) pair owns its accumulator scratch.  Tiles are
-(block_c, D) with D padded to the 128 lane width by the wrapper; the
-score matmul (G x D) @ (D x block_c) runs on the MXU.
+Grid: (B, C_tiles) — the cache dim is the sequential inner loop; each
+batch row owns its accumulator scratch.  A K/V tile is (block_c, K, D):
+every KV head of ``block_c`` slots.  The TPU lowering tiles the last two
+block dims, which must be multiples of (8, 128) or whole array dims, so the
+tile spans the full (K, D) pair of the (Bc, C, K, D) cache — the cache
+is read in its resident layout, never reshaped or copied (any D, e.g.
+phi3's 96).  In VMEM the tile is swapped to head-major (K, block_c, D) and
+both score and value contractions run as K-batched MXU matmuls; slot
+positions ride as a (Bc, 1, C) array so a tile's positions are one
+lane-dense (1, block_c) row.
 """
 
 from __future__ import annotations
@@ -36,25 +42,27 @@ from jax.experimental.pallas import tpu as pltpu
 __all__ = ["flash_decode_pallas"]
 
 NEG_INF = -1e30
+# fp32 MXU contractions (bf16 cache tiles are upcast first).
+_HIGHEST = jax.lax.Precision.HIGHEST
 
 
 def _kernel(
     rows_ref,  # (B,) SMEM scalar-prefetch: query row -> cache row
     qpos_ref,  # (B,) SMEM scalar-prefetch: per-query-row position
-    q_ref,  # (1, 1, G, D)
-    k_ref,  # (1, block_c, 1, D)
-    v_ref,  # (1, block_c, 1, D)
-    pos_ref,  # (1, block_c)  int32 per-sequence slot positions
-    o_ref,  # (1, 1, G, D) out
-    m_scr,  # (G,) scratch
-    l_scr,  # (G,)
-    acc_scr,  # (G, D)
+    q_ref,  # (1, K, G, D)
+    k_ref,  # (1, block_c, K, D)
+    v_ref,  # (1, block_c, K, D)
+    pos_ref,  # (1, 1, block_c)  int32 per-sequence slot positions
+    o_ref,  # (1, K, G, D) out
+    m_scr,  # (K, G, 1) scratch
+    l_scr,  # (K, G, 1)
+    acc_scr,  # (K, G, D)
     *,
     num_c_blocks: int,
     window: int,
     scale: float,
 ):
-    c = pl.program_id(2)
+    c = pl.program_id(1)
 
     @pl.when(c == 0)
     def _init():
@@ -62,34 +70,36 @@ def _kernel(
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    q = q_ref[0, 0].astype(jnp.float32) * scale  # (G, D)
-    k = k_ref[0, :, 0].astype(jnp.float32)  # (bc, D)
-    v = v_ref[0, :, 0].astype(jnp.float32)  # (bc, D)
-    kpos = pos_ref[0, :]  # (bc,)
+    q = q_ref[0].astype(jnp.float32) * scale  # (K, G, D)
+    k = jnp.swapaxes(k_ref[0].astype(jnp.float32), 0, 1)  # (K, bc, D)
+    v = jnp.swapaxes(v_ref[0].astype(jnp.float32), 0, 1)  # (K, bc, D)
+    kpos = pos_ref[0]  # (1, bc)
     qpos = qpos_ref[pl.program_id(0)]
 
     s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    )  # (G, bc)
+        q, k, (((2,), (2,)), ((0,), (0,))),
+        preferred_element_type=jnp.float32, precision=_HIGHEST,
+    )  # (K, G, bc)
     valid = (kpos >= 0) & (kpos <= qpos)
     if window > 0:
         valid &= qpos - kpos < window
-    s = jnp.where(valid[None, :], s, NEG_INF)
+    s = jnp.where(valid[None], s, NEG_INF)
 
     m_old = m_scr[...]
-    m_new = jnp.maximum(m_old, s.max(axis=-1))
-    p = jnp.exp(s - m_new[:, None])
+    m_new = jnp.maximum(m_old, s.max(axis=-1, keepdims=True))
+    p = jnp.exp(s - m_new)
     corr = jnp.exp(m_old - m_new)
-    l_scr[...] = l_scr[...] * corr + p.sum(axis=-1)
-    acc_scr[...] = acc_scr[...] * corr[:, None] + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-    )
+    l_scr[...] = l_scr[...] * corr + p.sum(axis=-1, keepdims=True)
+    acc_scr[...] = acc_scr[...] * corr + jax.lax.dot_general(
+        p, v, (((2,), (1,)), ((0,), (0,))),
+        preferred_element_type=jnp.float32, precision=_HIGHEST,
+    )  # (K, G, D)
     m_scr[...] = m_new
 
     @pl.when(c == num_c_blocks - 1)
     def _finalize():
-        o_ref[0, 0] = (
-            acc_scr[...] / jnp.maximum(l_scr[...], 1e-30)[:, None]
+        o_ref[0] = (
+            acc_scr[...] / jnp.maximum(l_scr[...], 1e-30)
         ).astype(o_ref.dtype)
 
 
@@ -105,9 +115,11 @@ def flash_decode_pallas(
     rows: jax.Array | None = None,  # (B,) int32 query row -> cache row
     *,
     window: int = 0,
-    block_c: int = 512,
+    block_c: int = 128,
     interpret: bool = False,
 ) -> jax.Array:
+    """``block_c`` must be a multiple of 128 (the lane width the slot
+    positions are tiled on)."""
     b, h, d = q.shape
     bc, c, kh, _ = k.shape
     g = h // kh
@@ -125,6 +137,7 @@ def flash_decode_pallas(
         k_pos = jnp.pad(k_pos, ((0, 0), (0, pc)), constant_values=-1)
     cc = k.shape[1]
     nc = cc // block_c
+    k_pos = k_pos.reshape(bc, 1, cc)
 
     qg = q.reshape(b, kh, g, d)
     # Per-row query positions (continuous batching) ride the same
@@ -134,28 +147,28 @@ def flash_decode_pallas(
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, kh, nc),
+        grid=(b, nc),
         in_specs=[
-            pl.BlockSpec((1, 1, g, d), lambda i, j, c_, rows_, qp_: (i, j, 0, 0)),
+            pl.BlockSpec((1, kh, g, d), lambda i, c_, rows_, qp_: (i, 0, 0, 0)),
             pl.BlockSpec(
-                (1, block_c, 1, d),
-                lambda i, j, c_, rows_, qp_: (rows_[i], c_, j, 0),
+                (1, block_c, kh, d),
+                lambda i, c_, rows_, qp_: (rows_[i], c_, 0, 0),
             ),
             pl.BlockSpec(
-                (1, block_c, 1, d),
-                lambda i, j, c_, rows_, qp_: (rows_[i], c_, j, 0),
+                (1, block_c, kh, d),
+                lambda i, c_, rows_, qp_: (rows_[i], c_, 0, 0),
             ),
             pl.BlockSpec(
-                (1, block_c), lambda i, j, c_, rows_, qp_: (rows_[i], c_)
+                (1, 1, block_c), lambda i, c_, rows_, qp_: (rows_[i], 0, c_)
             ),
         ],
         out_specs=pl.BlockSpec(
-            (1, 1, g, d), lambda i, j, c_, rows_, qp_: (i, j, 0, 0)
+            (1, kh, g, d), lambda i, c_, rows_, qp_: (i, 0, 0, 0)
         ),
         scratch_shapes=[
-            pltpu.VMEM((g,), jnp.float32),
-            pltpu.VMEM((g,), jnp.float32),
-            pltpu.VMEM((g, d), jnp.float32),
+            pltpu.VMEM((kh, g, 1), jnp.float32),
+            pltpu.VMEM((kh, g, 1), jnp.float32),
+            pltpu.VMEM((kh, g, d), jnp.float32),
         ],
     )
     out = pl.pallas_call(

@@ -23,21 +23,18 @@ runs the kernels in *interpret mode* (the kernel body executes as jax ops
 on CPU): bit-for-bit the same dataflow the TPU lowering compiles, correct
 but slow, which is exactly what the equivalence tests and the
 ``benchmarks/kernel_micro.py`` sweep exercise.  Each wrapper picks
-interpret mode automatically from the backend; pass ``interpret=``
-explicitly to override.
-
-All wrappers are shape-polymorphic the cheap way: they are ``jax.jit``-ed
-(and re-traced inside the tier runtime's per-(spec, bucket) segment
-cache), so a new *bucket* shape compiles once and a survivor-count change
-within a bucket never recompiles — the same contract as the jnp path.
+interpret mode from :func:`on_tpu` each time it is traced (the wrappers
+are not jitted themselves, so no cached trace outlives that decision);
+pass ``interpret=`` explicitly to override.  The ``*_pallas`` kernels
+they call are jitted, and are traced inside the tier runtime's
+per-(spec, bucket) segment functions, so a new *bucket* shape compiles
+once and a survivor-count change within a bucket never recompiles — the
+same contract as the jnp path.
 """
 
 from __future__ import annotations
 
-import functools
-
 import jax
-import jax.numpy as jnp
 
 from repro.kernels.entropy_exit import (
     entropy_exit_argmax_heads_pallas,
@@ -79,22 +76,23 @@ def resolve_use_kernels(flag: bool | None, *, sharded: bool = False) -> bool:
     return on_tpu() if flag is None else bool(flag)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
+def _interpret(flag: bool | None) -> bool:
+    return (not on_tpu()) if flag is None else flag
+
+
 def entropy_exit(logits, threshold, *, interpret: bool | None = None):
     """(B, V) logits -> (normalized entropy (B,), exit flags (B,))."""
-    interp = (not on_tpu()) if interpret is None else interpret
-    return entropy_exit_pallas(logits, threshold, interpret=interp)
+    return entropy_exit_pallas(logits, threshold,
+                               interpret=_interpret(interpret))
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
 def entropy_exit_argmax(logits, threshold, *, interpret: bool | None = None):
     """Fused exit decision: (B, V) logits -> (normalized entropy (B,),
     exit flags (B,), argmax token (B,) int32) in one streaming pass."""
-    interp = (not on_tpu()) if interpret is None else interpret
-    return entropy_exit_argmax_pallas(logits, threshold, interpret=interp)
+    return entropy_exit_argmax_pallas(logits, threshold,
+                                      interpret=_interpret(interpret))
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
 def entropy_exit_argmax_heads(logits, thresholds, *,
                               interpret: bool | None = None):
     """Multi-head fused exit decision: (K, B, V) stacked branch-head
@@ -104,35 +102,29 @@ def entropy_exit_argmax_heads(logits, thresholds, *,
     ``thresholds`` is a scalar (every head) or (K,) per-head array.
     Sharded segments never reach this wrapper: ``resolve_use_kernels``
     routes them to the jnp fallback (see ``serving.tiers``)."""
-    interp = (not on_tpu()) if interpret is None else interpret
     return entropy_exit_argmax_heads_pallas(logits, thresholds,
-                                            interpret=interp)
+                                            interpret=_interpret(interpret))
 
 
-@functools.partial(jax.jit, static_argnames=("window", "interpret"))
 def flash_decode(q, k, v, k_pos, q_pos, rows=None, *, window: int = 0,
                  interpret: bool | None = None):
     """Single-token GQA decode attention against a (ring) KV cache.
     ``rows`` maps a compacted survivor sub-batch onto cache rows."""
-    interp = (not on_tpu()) if interpret is None else interpret
     return flash_decode_pallas(q, k, v, k_pos, q_pos, rows, window=window,
-                               interpret=interp)
+                               interpret=_interpret(interpret))
 
 
-@functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
 def ssd_scan(x, a, b_mat, c_mat, *, chunk: int = 128,
              interpret: bool | None = None):
     """Mamba2 chunked SSD scan: (y, final_state)."""
-    interp = (not on_tpu()) if interpret is None else interpret
-    return ssd_scan_pallas(x, a, b_mat, c_mat, chunk=chunk, interpret=interp)
+    return ssd_scan_pallas(x, a, b_mat, c_mat, chunk=chunk,
+                           interpret=_interpret(interpret))
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
 def ssd_update(h_state, x, a, b_vec, c_vec, rows=None, *,
                interpret: bool | None = None):
     """One recurrent SSD decode step against the full-batch resident state;
     ``rows`` maps the sub-batch onto state rows (scalar-prefetch, no gather
     copy).  Returns (y (B,H,P), new state rows (B,H,P,N)), fp32."""
-    interp = (not on_tpu()) if interpret is None else interpret
     return ssd_update_pallas(h_state, x, a, b_vec, c_vec, rows,
-                             interpret=interp)
+                             interpret=_interpret(interpret))

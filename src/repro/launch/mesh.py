@@ -6,6 +6,10 @@ before any jax initialization.
 
 Target fleet: TPU v5e, 256 chips/pod (16x16), 2 pods = 512 chips.
 Axes: ("data", "model") single-pod; ("pod", "data", "model") multi-pod.
+Every axis is ``AxisType.Auto``: shardings propagate through XLA's SPMD
+partitioner from the placed params and the models' ``constrain`` calls.
+(``jax.make_mesh`` defaults to explicit axes, which put shardings into
+array types and refuse, e.g., a gather from a vocab-sharded embedding.)
 """
 
 from __future__ import annotations
@@ -22,10 +26,16 @@ __all__ = [
 ]
 
 
+def _auto_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
+    )
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_local_mesh(*, data: int | None = None, model: int | None = None):
@@ -53,7 +63,7 @@ def make_local_mesh(*, data: int | None = None, model: int | None = None):
             f"XLA_FLAGS=--xla_force_host_platform_device_count=N before "
             f"jax initializes to virtualize more)"
         )
-    return jax.make_mesh((data, model), ("data", "model"))
+    return _auto_mesh((data, model), ("data", "model"))
 
 
 def mesh_axis_sizes(mesh) -> dict[str, int]:

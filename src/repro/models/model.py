@@ -228,7 +228,6 @@ def run_trunk(
         breaks.append(acc)
 
     new_caches = dict(caches) if caches is not None else None
-    cache_pieces: dict[str, list] = {name: [] for name, _, _ in layout}
     aux = jnp.zeros((), jnp.float32)
     collected: dict[int, jax.Array] = {}
 
@@ -238,27 +237,21 @@ def run_trunk(
         for name, kind, n in layout:
             s_lo, s_hi = stack_bounds[name]
             if s_lo <= seg_lo < s_hi:
-                rel_lo, rel_hi = seg_lo - s_lo, seg_hi - s_lo
-                sp = stack_slice(params[name], rel_lo, rel_hi)
-                sc = (
-                    stack_slice(caches[name], rel_lo, rel_hi)
-                    if caches is not None
-                    else None
+                cross = (
+                    caches["cross_kv"]
+                    if kind.cross_attention and caches is not None else None
                 )
-                cross = None
-                if kind.cross_attention and caches is not None:
-                    cross = jax.tree_util.tree_map(
-                        lambda a: a[rel_lo:rel_hi], caches["cross_kv"]
-                    )
                 h, nc, a = run_stack(
-                    sp, h, cfg, kind, positions, sc, cross,
+                    params[name], h, cfg, kind, positions,
+                    new_caches[name] if caches is not None else None, cross,
+                    layers=(seg_lo - s_lo, seg_hi - s_lo),
                     remat=remat, moe_dispatch=moe_dispatch, rows=rows,
                     use_kernels=use_kernels,
                 )
                 h = constrain(h, "b..")
                 aux = aux + a
-                if nc is not None and caches is not None:
-                    cache_pieces[name].append((rel_lo, rel_hi, nc))
+                if nc is not None:
+                    new_caches[name] = nc
                 break
         else:
             raise AssertionError("segment outside all stacks")
@@ -285,20 +278,6 @@ def run_trunk(
 
         if seg_hi in collect:
             collected[seg_hi] = h
-
-    if new_caches is not None:
-        for name, pieces in cache_pieces.items():
-            if not pieces:
-                continue
-            updated = new_caches[name]
-            for rel_lo, rel_hi, nc in pieces:
-                updated = jax.tree_util.tree_map(
-                    lambda full, piece, lo_=rel_lo: jax.lax.dynamic_update_slice_in_dim(
-                        full, piece.astype(full.dtype), lo_, axis=0
-                    ),
-                    updated, nc,
-                )
-            new_caches[name] = updated
 
     return h, new_caches, aux, collected
 

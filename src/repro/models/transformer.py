@@ -192,12 +192,13 @@ def run_stack(
     caches: Params | None = None,  # stacked along layer axis
     cross_kv: tuple[jax.Array, jax.Array] | None = None,  # stacked (L, B, S, K, D)
     *,
+    layers: tuple[int, int] | None = None,  # [lo, hi) of the stack; None = all
     remat: bool = False,
     moe_dispatch: str = "einsum",
     rows: jax.Array | None = None,
     use_kernels: bool = False,
 ) -> tuple[jax.Array, Params | None, jax.Array]:
-    """Scan the blocks of a (slice of a) stack over the residual stream.
+    """Scan blocks ``layers`` of a stack over the residual stream.
 
     Returns (h, new stacked caches, summed aux loss).  ``rows`` threads the
     survivor-compaction row map into every stateful block (decode only);
@@ -208,6 +209,11 @@ def run_stack(
     if caches is None:
         # Stateless (training / cache-free prefill): params (+cross KV) are
         # scan inputs; nothing is carried but the residual stream.
+        if layers is not None:
+            stacked_params = stack_slice(stacked_params, *layers)
+            if cross_kv is not None:
+                cross_kv = stack_slice(cross_kv, *layers)
+
         def body(carry, xs):
             h = carry
             lparams, lcross = xs
@@ -230,16 +236,25 @@ def run_stack(
     # Stateful (decode / cache-writing prefill): the FULL stacked cache is a
     # loop carry updated in place at the layer index — this lets XLA alias
     # the cache buffers instead of double-buffering a scan ys output (which
-    # costs ~2x cache HBM at 32k contexts; see EXPERIMENTS §Perf).
-    def body_cache(carry, xs):
-        h, cache_full, i = carry
-        lparams, lcross = xs
-        lcache = jax.tree_util.tree_map(
-            lambda a: jax.lax.dynamic_index_in_dim(a, i, 0, keepdims=False),
-            cache_full,
+    # costs ~2x cache HBM at 32k contexts; see EXPERIMENTS §Perf).  Params,
+    # cache and cross K/V are indexed per layer inside the body rather than
+    # sliced to ``layers`` up front: a static slice of the stack is
+    # materialized as a copy (16 of phi3-mini's 32 layers: 3.5 GB of
+    # weights plus the cache slice), which a 16 GB chip cannot spare.
+    lo, hi = layers or (0, jax.tree_util.tree_leaves(stacked_params)[0].shape[0])
+
+    def pick(i):
+        return lambda a: jax.lax.dynamic_index_in_dim(a, i, 0, keepdims=False)
+
+    def body_cache(carry, i):
+        h, cache_full = carry
+        lcross = (
+            None if cross_kv is None
+            else jax.tree_util.tree_map(pick(i), cross_kv)
         )
         h, new_cache, aux = block_apply(
-            lparams, h, cfg, kind, positions, lcache, lcross,
+            jax.tree_util.tree_map(pick(i), stacked_params), h, cfg, kind,
+            positions, jax.tree_util.tree_map(pick(i), cache_full), lcross,
             moe_dispatch=moe_dispatch, rows=rows, use_kernels=use_kernels,
         )
         cache_full = jax.tree_util.tree_map(
@@ -248,11 +263,11 @@ def run_stack(
             ),
             cache_full, new_cache,
         )
-        return (h, cache_full, i + 1), aux
+        return (h, cache_full), aux
 
     if remat:
         body_cache = jax.checkpoint(body_cache, prevent_cse=False)
-    (h, new_caches, _), auxes = jax.lax.scan(
-        body_cache, (h, caches, jnp.zeros((), jnp.int32)), (stacked_params, cross_kv)
+    (h, new_caches), auxes = jax.lax.scan(
+        body_cache, (h, caches), jnp.arange(lo, hi, dtype=jnp.int32)
     )
     return h, new_caches, jnp.sum(auxes)

@@ -14,7 +14,10 @@ a plain single-device run they skip.  The cost-model tests always run.
 
 SPMD partial-sum all-reduces may reorder float accumulation, so the
 equivalence contract is *trajectory* identity — greedy tokens, exit
-masks, shipped counts per step — not bitwise logits.
+masks, shipped counts per step — not bitwise logits.  The dense model
+computes in float32: with bf16 activations a reordered sum moves a logit
+by up to a bf16 ulp, which flips a near-tied greedy token of a random-init
+model (the mesh and one device then diverge legitimately).
 """
 
 import dataclasses
@@ -46,7 +49,8 @@ multi_device = pytest.mark.skipif(
 def gqa_model():
     """4-layer GQA trunk (qwen3_8b smoke), branches after v_1 and v_3."""
     cfg = dataclasses.replace(
-        get_smoke_config("qwen3_8b"), num_layers=4, branch_layers=(1, 3)
+        get_smoke_config("qwen3_8b"), num_layers=4, branch_layers=(1, 3),
+        dtype="float32",
     )
     return cfg, M.init_params(jax.random.PRNGKey(0), cfg)
 

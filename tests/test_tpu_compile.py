@@ -1,0 +1,179 @@
+"""Compile the decode-path Pallas kernels for a TPU v5e that is described,
+not attached: the TPU compiler refuses block shapes, layouts and VMEM use
+that interpret mode (every other kernel test) never checks.
+
+Shapes are the published widths of the served configurations (phi3-mini,
+qwen3-8b, mamba2-130m).  Nothing runs; each test asserts the compiled
+program holds the Mosaic kernel (``tpu_custom_call``), i.e. no jnp or
+interpreted fallback was compiled in its place.
+"""
+
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.configs import get_config
+from repro.kernels import ops
+from repro.kernels.entropy_exit import (
+    entropy_exit_argmax_heads_pallas,
+    entropy_exit_argmax_pallas,
+    entropy_exit_pallas,
+)
+from repro.kernels.flash_decode import flash_decode_pallas
+from repro.kernels.ssd_scan import ssd_scan_pallas, ssd_update_pallas
+
+BF16, F32, I32 = jnp.bfloat16, jnp.float32, jnp.int32
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # no TPU compiler installed / usable here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def no_persistent_cache():
+    """A compile for a described chip is written to the persistent cache
+    but cannot be read back without one; keep these compiles out of it."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+@pytest.fixture
+def compile_tpu(one_chip, no_persistent_cache):
+    """fn, *(shape, dtype) -> compiled HLO text for the described chip."""
+
+    def go(fn, *args):
+        sds = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in args]
+        return jax.jit(fn).lower(*sds).compile().as_text()
+
+    return go
+
+
+@pytest.mark.parametrize(
+    "b,h,kh,d,c",
+    [
+        (8, 32, 32, 96, 4096),  # phi3-mini: MHA, head_dim 96
+        (8, 32, 8, 128, 4096),  # qwen3-8b: GQA 4:1
+    ],
+)
+def test_flash_decode_compiles(compile_tpu, b, h, kh, d, c):
+    text = compile_tpu(
+        lambda q, k, v, kp, qp, rows: flash_decode_pallas(
+            q, k, v, kp, qp, rows, interpret=False
+        ),
+        ((b, h, d), BF16), ((b, c, kh, d), BF16), ((b, c, kh, d), BF16),
+        ((b, c), I32), ((b,), I32), ((b,), I32),
+    )
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("b", [8, 32])
+def test_entropy_exit_argmax_compiles(compile_tpu, b):
+    text = compile_tpu(
+        lambda l: entropy_exit_argmax_pallas(l, 0.5, interpret=False),
+        ((b, 32064), BF16),
+    )
+    assert "tpu_custom_call" in text
+
+
+def test_entropy_exit_compiles(compile_tpu):
+    text = compile_tpu(
+        lambda l: entropy_exit_pallas(l, 0.5, interpret=False),
+        ((32, 32064), BF16),
+    )
+    assert "tpu_custom_call" in text
+
+
+def test_entropy_exit_argmax_heads_compiles(compile_tpu):
+    text = compile_tpu(
+        lambda l, t: entropy_exit_argmax_heads_pallas(l, t, interpret=False),
+        ((3, 8, 32064), BF16), ((3,), F32),
+    )
+    assert "tpu_custom_call" in text
+
+
+def test_ssd_update_compiles(compile_tpu):
+    # mamba2-130m decode: B8, H24, P64, N128, one B/C group.
+    text = compile_tpu(
+        lambda hs, x, a, bv, cv, rows: ssd_update_pallas(
+            hs, x, a, bv, cv, rows, interpret=False
+        ),
+        ((8, 24, 64, 128), F32), ((8, 24, 64), F32), ((8, 24), F32),
+        ((8, 1, 128), F32), ((8, 1, 128), F32), ((8,), I32),
+    )
+    assert "tpu_custom_call" in text
+
+
+def test_ssd_scan_compiles(compile_tpu):
+    # mamba2-130m heads over a 2048-token prefill, 256-token chunks.
+    text = compile_tpu(
+        lambda x, a, bm, cm: ssd_scan_pallas(
+            x, a, bm, cm, chunk=256, interpret=False
+        ),
+        ((2, 2048, 24, 64), BF16), ((2, 2048, 24), F32),
+        ((2, 2048, 24, 128), BF16), ((2, 2048, 24, 128), BF16),
+    )
+    assert "tpu_custom_call" in text
+
+
+def test_phi3_tier_segment_compiles(one_chip, no_persistent_cache,
+                                    monkeypatch):
+    """A 2-layer phi3-mini decode segment at published widths (bf16
+    params, one branch head) with kernels on auto: steered to "on TPU",
+    the segment must compile with flash_decode and the fused exit
+    decision as Mosaic kernels."""
+    from repro.models import model as M
+    from repro.serving.tiers import TierExecutor, segments_for_cuts
+
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)
+    cfg = dataclasses.replace(
+        get_config("phi3_mini_3_8b"), num_layers=2, branch_layers=(1,),
+        param_dtype="bfloat16",
+    )
+    batch, context = 8, 1024
+
+    def placed(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip),
+            tree,
+        )
+
+    params = placed(jax.eval_shape(
+        lambda k: M.init_params(k, cfg), jax.random.PRNGKey(0)
+    ))
+    caches = placed(jax.eval_shape(
+        lambda: M.init_caches(cfg, batch, context)
+    ))
+    ex = TierExecutor(cfg, params, segments_for_cuts(cfg, ()))
+    assert ex.use_kernels
+    tok, pos, exited = placed((
+        jax.ShapeDtypeStruct((batch, 1), I32),
+        jax.ShapeDtypeStruct((batch,), I32),
+        jax.ShapeDtypeStruct((batch,), jnp.bool_),
+    ))
+    compiled = ex.segment_fn(0).lower(
+        params, tok, pos, exited, pos, caches
+    ).compile()
+    assert compiled.as_text().count("tpu_custom_call") >= 2
