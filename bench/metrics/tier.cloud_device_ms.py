@@ -1,0 +1,14 @@
+"""Model step: device time of the later tiers' decode programs
+(``decode_l{lo}_*`` with ``lo`` > 0, every bucket) in the traced slice per
+traced decode step, in milliseconds."""
+
+from bench import program_spans as P
+
+
+def read(run):
+    tr, n = run.trace, P.traced_step_count(run)
+    if not tr or n <= 0:
+        return None
+    ns = P.module_ns(tr["modules"][tr["chips"][0]], P.is_cloud,
+                     tr["lo"], tr["hi"])
+    return None if ns is None else ns / 1e6 / n

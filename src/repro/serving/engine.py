@@ -95,7 +95,8 @@ class ServingEngine(ServesRequests):
         # must run on the same placement.
         self.params = self._exec.params
         self._prefill = self._exec._jit(
-            lambda params, inputs, caches: M.prefill(params, inputs, cfg, caches)
+            lambda params, inputs, caches: M.prefill(params, inputs, cfg, caches),
+            "prefill_batch",
         )
 
     @property

@@ -59,8 +59,10 @@ from typing import Any, Callable, Sequence
 
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 from repro.core.multitier import bucket_for
+from repro.serving import spans
 
 __all__ = [
     "Request",
@@ -336,8 +338,13 @@ class RequestScheduler:
         slots.  Returns None when there is nothing to do (idle step: no
         live request and nothing admissible yet advances the step clock,
         so simulated arrivals keyed on ``arrival_step`` still progress)."""
-        self._mark_arrivals()
-        admitted = self._admit()
+        with StepTraceAnnotation(spans.SCHED_STEP, step_num=self.step_count):
+            return self._step()
+
+    def _step(self) -> SchedulerStepReport | None:
+        with TraceAnnotation(spans.SCHED_ADMIT):
+            self._mark_arrivals()
+            admitted = self._admit()
         if not self.active.any():
             if self.queue:
                 self.step_count += 1  # idle tick toward future arrivals
@@ -345,79 +352,81 @@ class RequestScheduler:
         rep, self.caches = self.server.step(
             self.tok_dev, self.pos.copy(), self.caches, active=self.active
         )
-        now = self.clock()
-        self.step_count += 1
-        self.decode_steps += 1
-        # Servers wrap the executor's TierStepResult in their own report;
-        # the raw result carries the uniform per-slot fields.
-        res = getattr(rep, "tier_result", rep)
-        tokens = np.asarray(res.tokens)
-        exited = np.asarray(res.exited)
-        exit_tier = np.asarray(res.exit_tier)
-        deg_mask = getattr(res, "degraded", None)
-        fail_mask = getattr(res, "failed", None)
-        self.tok_dev = res.tokens_dev[:, None]
+        with TraceAnnotation(spans.SCHED_EMIT):
+            now = self.clock()
+            self.step_count += 1
+            self.decode_steps += 1
+            # Servers wrap the executor's TierStepResult in their own report;
+            # the raw result carries the uniform per-slot fields.
+            res = getattr(rep, "tier_result", rep)
+            tokens = np.asarray(res.tokens)
+            exited = np.asarray(res.exited)
+            exit_tier = np.asarray(res.exit_tier)
+            deg_mask = getattr(res, "degraded", None)
+            fail_mask = getattr(res, "failed", None)
+            self.tok_dev = res.tokens_dev[:, None]
 
-        emitted: dict[int, int] = {}
-        retired: list[int] = []
-        degraded: list[int] = []
-        failed: list[int] = []
-        live = int(self.active.sum())
-        for slot in np.flatnonzero(self.active):
-            req = self._slot_req[slot]
-            r = self.results[req.rid]
-            if fail_mask is not None and fail_mask[slot]:
-                # Unrecoverable hop fault: no token this step.  Reclaim
-                # the slot either way; the request re-queues (fresh
-                # admission, fresh result) or retires terminally failed.
-                self.active[slot] = False
-                self._slot_req[slot] = None
-                failed.append(req.rid)
-                if (
-                    self.requeue_on_fail
-                    and req._requeues < self.max_requeues
+            emitted: dict[int, int] = {}
+            retired: list[int] = []
+            degraded: list[int] = []
+            failed: list[int] = []
+            live = int(self.active.sum())
+            for slot in np.flatnonzero(self.active):
+                req = self._slot_req[slot]
+                r = self.results[req.rid]
+                if fail_mask is not None and fail_mask[slot]:
+                    # Unrecoverable hop fault: no token this step.  Reclaim
+                    # the slot either way; the request re-queues (fresh
+                    # admission, fresh result) or retires terminally failed.
+                    self.active[slot] = False
+                    self._slot_req[slot] = None
+                    failed.append(req.rid)
+                    if (
+                        self.requeue_on_fail
+                        and req._requeues < self.max_requeues
+                    ):
+                        req._requeues += 1
+                        r.status = "requeued"
+                        self.queue.appendleft(req)
+                    else:
+                        r.done = True
+                        r.status = "failed"
+                        r.retired_step = self.step_count
+                        r.latency_s = now - req.arrival_s
+                        self.finished.append(req.rid)
+                        retired.append(req.rid)
+                    continue
+                tok = int(tokens[slot])
+                emitted[req.rid] = tok
+                r.tokens.append(tok)
+                r.exited.append(bool(exited[slot]))
+                r.exit_tiers.append(int(exit_tier[slot]))
+                if deg_mask is not None and deg_mask[slot]:
+                    r.degraded_tokens += 1
+                    degraded.append(req.rid)
+                if r.ttft_s is None:
+                    r.ttft_s = now - req.arrival_s
+                self.pos[slot] += 1
+                self._remaining[slot] -= 1
+                self.total_tokens += 1
+                if self._remaining[slot] <= 0 or (
+                    req.stop_on_exit and exited[slot]
                 ):
-                    req._requeues += 1
-                    r.status = "requeued"
-                    self.queue.appendleft(req)
-                else:
                     r.done = True
-                    r.status = "failed"
+                    r.status = "degraded" if r.degraded_tokens else "ok"
                     r.retired_step = self.step_count
                     r.latency_s = now - req.arrival_s
+                    self.active[slot] = False
+                    self._slot_req[slot] = None
                     self.finished.append(req.rid)
                     retired.append(req.rid)
-                continue
-            tok = int(tokens[slot])
-            emitted[req.rid] = tok
-            r.tokens.append(tok)
-            r.exited.append(bool(exited[slot]))
-            r.exit_tiers.append(int(exit_tier[slot]))
-            if deg_mask is not None and deg_mask[slot]:
-                r.degraded_tokens += 1
-                degraded.append(req.rid)
-            if r.ttft_s is None:
-                r.ttft_s = now - req.arrival_s
-            self.pos[slot] += 1
-            self._remaining[slot] -= 1
-            self.total_tokens += 1
-            if self._remaining[slot] <= 0 or (
-                req.stop_on_exit and exited[slot]
-            ):
-                r.done = True
-                r.status = "degraded" if r.degraded_tokens else "ok"
-                r.retired_step = self.step_count
-                r.latency_s = now - req.arrival_s
-                self.active[slot] = False
-                self._slot_req[slot] = None
-                self.finished.append(req.rid)
-                retired.append(req.rid)
         if retired and self.reset_on_retire:
-            rows = np.full(
-                bucket_for(len(retired), self.slots), self.slots, np.int32
-            )
-            rows[: len(retired)] = [self.results[r].slot for r in retired]
-            self.caches = self.executor.reset_rows(self.caches, rows)
+            with TraceAnnotation(spans.SCHED_RETIRE):
+                rows = np.full(
+                    bucket_for(len(retired), self.slots), self.slots, np.int32
+                )
+                rows[: len(retired)] = [self.results[r].slot for r in retired]
+                self.caches = self.executor.reset_rows(self.caches, rows)
         report = SchedulerStepReport(
             step=self.step_count,
             live=live,

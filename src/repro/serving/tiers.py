@@ -243,7 +243,11 @@ Segment functions are cached by ``(spec, bucket)`` where spec is
 ``(layer_lo, layer_hi, branches, head, probe)``: a repartition that moves
 one cut re-uses the jitted callables of every unchanged tier segment, and
 a survivor-count change *within* a bucket re-jits nothing
-(``trace_counts`` exposes this for tests).
+(``trace_counts`` exposes this for tests).  Each program is named after its
+key — ``decode_l{lo}_{hi}_b{bucket|full}`` (plus ``_probe`` /
+``_degrade{layer}``), ``prefill_p{plen}_n{n}``, ``reset_rows_n{n}`` — and
+the step's host phases carry profiler spans (:mod:`repro.serving.spans`),
+so a trace charges device time and idle gaps to both.
 
 Mesh-sharded tier segments (``mesh`` / ``sharding``)
 ----------------------------------------------------
@@ -323,11 +327,13 @@ from typing import Any, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.configs.base import ModelConfig
 from repro.core.calibration import normalized_entropy
 from repro.core.multitier import bucket_for, bucket_ladder
 from repro.kernels import ops as kernel_ops
+from repro.serving import spans
 from repro.serving.faults import (
     CircuitBreaker,
     FaultEvent,
@@ -712,21 +718,24 @@ class TierExecutor:
             return caches
         return self.policy.shard_caches(caches)
 
-    def _jit(self, fn):
-        """``jax.jit`` with the executor's activation-sharding context
-        active at trace time (jit executes the traced body once), so the
-        model stack's ``constrain`` call sites emit real constraints on a
-        sharded executor and stay no-ops otherwise."""
-        if not self.sharded:
-            return jax.jit(fn)
-        pol = self.policy
+    def _jit(self, fn, name: str):
+        """``jax.jit`` of ``fn`` as the program ``name`` (its module is
+        ``jit_<name>`` in HLO dumps and profiler traces), with the
+        executor's activation-sharding context active at trace time (jit
+        executes the traced body once), so the model stack's ``constrain``
+        call sites emit real constraints on a sharded executor and stay
+        no-ops otherwise."""
+        if self.sharded:
+            pol = self.policy
+            body = fn
 
-        def traced(*args):
-            with activation_sharding(pol.mesh, pol.batch_axes,
-                                     pol.model_axis):
-                return fn(*args)
+            def fn(*args):
+                with activation_sharding(pol.mesh, pol.batch_axes,
+                                         pol.model_axis):
+                    return body(*args)
 
-        return jax.jit(traced)
+        fn.__name__ = fn.__qualname__ = name
+        return jax.jit(fn)
 
     def _segment_fn(
         self,
@@ -815,139 +824,151 @@ class TierExecutor:
                 pos[None].astype(jnp.int32) if pos.ndim == 0
                 else pos[:, None].astype(jnp.int32)
             )
-            if bucket is None:
-                xb, ex, ch, rows, rows_rw = x, exited, chosen, None, None
-            else:
-                # ---- compact: survivors first (stable -> original order),
-                # then already-exited padding rows up to the bucket width.
-                order = jnp.argsort(exited, stable=True)
-                rows = order[:bucket]
-                xb = x[rows]
-                ex, ch = exited[rows], chosen[rows]
-                if positions.ndim == 2:
-                    positions = positions[rows]
-                # Padding rows read clamped garbage (discarded) and carry
-                # an out-of-bounds sentinel so their cache writes drop:
-                # downstream KV validity is a pure function of exits, not
-                # of which rows happened to pad the bucket.
-                rows_rw = jnp.where(ex, batch, rows).astype(jnp.int32)
-            h = embed_decode(params, xb, positions, cfg) if lo == 0 else xb
-            h, new_caches, _, collected = run_trunk(
-                params, h, cfg, positions, caches,
-                layer_range=(lo, hi), collect=eval_layers, rows=rows_rw,
-                use_kernels=use_kernels,
-            )
-            sub = xb.shape[0]
-            if probe_m is not None:
-                # Sampled probe: the extra heads run on probe_m rows only.
-                # probe_rows are original-batch indices; fold them into the
-                # sub-batch coordinate space (compacted tiers run a dense
-                # permutation of it) and remember which batch rows that
-                # covers for the report.
-                pr_idx = probe_rows.astype(jnp.int32) % sub
-            else:
-                pr_idx = None
-            if batched_heads:
-                # ---- batched heads: the segment's kept branches, probe
-                # heads and degrade fallback evaluate as ONE stacked
-                # (K, sub, D) projection against the shared unembedding +
-                # ONE multi-head entropy/flag/argmax launch.  A sampled
-                # probe (probe_m) runs at a different width, so its heads
-                # form a second (K_probe, probe_m, D) stack — still one
-                # projection + one launch for all probe heads.  Exit
-                # precedence is applied afterwards on the per-head (B,)
-                # rows in the same sorted-layer order as the sequential
-                # path; the per-head kernel outputs are mask-independent,
-                # so the result is bitwise identical.
-                main_layers = (
-                    eval_layers if probe_m is None
-                    else tuple(sorted({*branches, *extra}))
-                )
-                mls, mlg = branch_logits_stacked(
-                    params, collected, cfg, main_layers
-                )
-                dec = {} if mlg is None else head_decisions(mls, mlg[:, :, 0])
-                pdec = dec
-                if probe_m is not None and probe:
-                    probe_hidden = {l: collected[l][pr_idx] for l in probe}
-                    pls, plg = branch_logits_stacked(
-                        params, probe_hidden, cfg, tuple(sorted(probe))
-                    )
-                    pdec = head_decisions(pls, plg[:, :, 0])
-                bl = blp = None
-            else:
-                # ---- sequential reference path: one projection + one
-                # exit-decision launch per head (the parity baseline).
-                if probe_m is not None:
-                    plan_hidden = {
-                        l: collected[l] for l in {*branches, *extra}
-                    }
-                    probe_hidden = {l: collected[l][pr_idx] for l in probe}
-                    bl = branch_logits_per_head(params, plan_hidden, cfg)
-                    blp = branch_logits_per_head(params, probe_hidden, cfg)
+            with jax.named_scope("compact"):
+                if bucket is None:
+                    xb, ex, ch, rows, rows_rw = x, exited, chosen, None, None
                 else:
-                    bl = branch_logits_per_head(params, collected, cfg)
-                    blp = bl
-            takes, ents, ptakes, pents = [], [], [], []
-            for layer in eval_layers:
-                if layer in plan_set:
-                    if batched_heads:
-                        e, flag, btok = dec[layer]
-                        take = flag & ~ex
-                    else:
-                        take, e, btok = exit_decision(bl[layer][:, 0], ex)
-                    ch = jnp.where(take, btok, ch)
-                    ex = ex | take
-                    takes.append(take)
-                    ents.append(e)
-                elif layer in probe_set:
-                    # probe: report-only, never alters the trajectory
-                    exp = ex if pr_idx is None else ex[pr_idx]
-                    if batched_heads:
-                        e, flag, _ = pdec[layer]
-                        take = flag & ~exp
-                    else:
-                        take, e, _ = exit_decision(blp[layer][:, 0], exp)
-                    ptakes.append(take)
-                    pents.append(e)
-                # else: the degrade fallback head, consumed below.
-            if degrade is not None:
-                # Degraded terminal segment: force-finalize every
-                # still-unexited row from the fallback head (threshold
-                # ignored — the link below is dead, this IS the answer)
-                # and advance the cache step clock, which normally
-                # happens on the head tier.  The batched path reads the
-                # fallback token from the stacked launch's argmax row
-                # (bitwise jnp.argmax, see kernels/entropy_exit.py).
-                dtok = (
-                    dec[degrade][2] if batched_heads
-                    else jnp.argmax(bl[degrade][:, 0], -1).astype(jnp.int32)
+                    # ---- compact: survivors first (stable -> original order),
+                    # then already-exited padding rows up to the bucket width.
+                    order = jnp.argsort(exited, stable=True)
+                    rows = order[:bucket]
+                    xb = x[rows]
+                    ex, ch = exited[rows], chosen[rows]
+                    if positions.ndim == 2:
+                        positions = positions[rows]
+                    # Padding rows read clamped garbage (discarded) and carry
+                    # an out-of-bounds sentinel so their cache writes drop:
+                    # downstream KV validity is a pure function of exits, not
+                    # of which rows happened to pad the bucket.
+                    rows_rw = jnp.where(ex, batch, rows).astype(jnp.int32)
+            with jax.named_scope("layers"):
+                h = embed_decode(params, xb, positions, cfg) if lo == 0 else xb
+                h, new_caches, _, collected = run_trunk(
+                    params, h, cfg, positions, caches,
+                    layer_range=(lo, hi), collect=eval_layers, rows=rows_rw,
+                    use_kernels=use_kernels,
                 )
-                ch = jnp.where(ex, ch, dtok)
-                ex = jnp.ones_like(ex)
-                new_caches = dict(new_caches)
-                new_caches["length"] = caches["length"] + 1
-            psub = sub if probe_m is None else probe_m
-            take_s = jnp.stack(takes) if takes else jnp.zeros((0, sub), bool)
-            ents_s = (
-                jnp.stack(ents) if ents else jnp.zeros((0, sub), jnp.float32)
-            )
-            ptake_s = (
-                jnp.stack(ptakes) if ptakes else jnp.zeros((0, psub), bool)
-            )
-            pents_s = (
-                jnp.stack(pents) if pents else jnp.zeros((0, psub), jnp.float32)
-            )
+            with jax.named_scope("exit_heads"):
+                sub = xb.shape[0]
+                if probe_m is not None:
+                    # Sampled probe: the extra heads run on probe_m rows only.
+                    # probe_rows are original-batch indices; fold them into the
+                    # sub-batch coordinate space (compacted tiers run a dense
+                    # permutation of it) and remember which batch rows that
+                    # covers for the report.
+                    pr_idx = probe_rows.astype(jnp.int32) % sub
+                else:
+                    pr_idx = None
+                if batched_heads:
+                    # ---- batched heads: the segment's kept branches, probe
+                    # heads and degrade fallback evaluate as ONE stacked
+                    # (K, sub, D) projection against the shared unembedding +
+                    # ONE multi-head entropy/flag/argmax launch.  A sampled
+                    # probe (probe_m) runs at a different width, so its heads
+                    # form a second (K_probe, probe_m, D) stack — still one
+                    # projection + one launch for all probe heads.  Exit
+                    # precedence is applied afterwards on the per-head (B,)
+                    # rows in the same sorted-layer order as the sequential
+                    # path; the per-head kernel outputs are mask-independent,
+                    # so the result is bitwise identical.
+                    main_layers = (
+                        eval_layers if probe_m is None
+                        else tuple(sorted({*branches, *extra}))
+                    )
+                    mls, mlg = branch_logits_stacked(
+                        params, collected, cfg, main_layers
+                    )
+                    dec = (
+                        {} if mlg is None
+                        else head_decisions(mls, mlg[:, :, 0])
+                    )
+                    pdec = dec
+                    if probe_m is not None and probe:
+                        probe_hidden = {l: collected[l][pr_idx] for l in probe}
+                        pls, plg = branch_logits_stacked(
+                            params, probe_hidden, cfg, tuple(sorted(probe))
+                        )
+                        pdec = head_decisions(pls, plg[:, :, 0])
+                    bl = blp = None
+                else:
+                    # ---- sequential reference path: one projection + one
+                    # exit-decision launch per head (the parity baseline).
+                    if probe_m is not None:
+                        plan_hidden = {
+                            l: collected[l] for l in {*branches, *extra}
+                        }
+                        probe_hidden = {l: collected[l][pr_idx] for l in probe}
+                        bl = branch_logits_per_head(params, plan_hidden, cfg)
+                        blp = branch_logits_per_head(params, probe_hidden, cfg)
+                    else:
+                        bl = branch_logits_per_head(params, collected, cfg)
+                        blp = bl
+                takes, ents, ptakes, pents = [], [], [], []
+                for layer in eval_layers:
+                    if layer in plan_set:
+                        if batched_heads:
+                            e, flag, btok = dec[layer]
+                            take = flag & ~ex
+                        else:
+                            take, e, btok = exit_decision(bl[layer][:, 0], ex)
+                        ch = jnp.where(take, btok, ch)
+                        ex = ex | take
+                        takes.append(take)
+                        ents.append(e)
+                    elif layer in probe_set:
+                        # probe: report-only, never alters the trajectory
+                        exp = ex if pr_idx is None else ex[pr_idx]
+                        if batched_heads:
+                            e, flag, _ = pdec[layer]
+                            take = flag & ~exp
+                        else:
+                            take, e, _ = exit_decision(blp[layer][:, 0], exp)
+                        ptakes.append(take)
+                        pents.append(e)
+                    # else: the degrade fallback head, consumed below.
+                if degrade is not None:
+                    # Degraded terminal segment: force-finalize every
+                    # still-unexited row from the fallback head (threshold
+                    # ignored — the link below is dead, this IS the answer)
+                    # and advance the cache step clock, which normally
+                    # happens on the head tier.  The batched path reads the
+                    # fallback token from the stacked launch's argmax row
+                    # (bitwise jnp.argmax, see kernels/entropy_exit.py).
+                    dtok = (
+                        dec[degrade][2] if batched_heads
+                        else jnp.argmax(bl[degrade][:, 0], -1)
+                        .astype(jnp.int32)
+                    )
+                    ch = jnp.where(ex, ch, dtok)
+                    ex = jnp.ones_like(ex)
+                    new_caches = dict(new_caches)
+                    new_caches["length"] = caches["length"] + 1
+                psub = sub if probe_m is None else probe_m
+                take_s = (
+                    jnp.stack(takes) if takes else jnp.zeros((0, sub), bool)
+                )
+                ents_s = (
+                    jnp.stack(ents) if ents
+                    else jnp.zeros((0, sub), jnp.float32)
+                )
+                ptake_s = (
+                    jnp.stack(ptakes) if ptakes else jnp.zeros((0, psub), bool)
+                )
+                pents_s = (
+                    jnp.stack(pents) if pents
+                    else jnp.zeros((0, psub), jnp.float32)
+                )
             out: dict[str, Any] = {"caches": new_caches}
             logits = None
-            if head:
-                hF = norm_apply(cfg.norm_type, params["final_norm"], h)
-                logits = _unembed(params, hF, cfg)[:, 0]
-                ch = jnp.where(
-                    ex, ch, jnp.argmax(logits, -1).astype(jnp.int32)
-                )
-                out["caches"] = dict(out["caches"])
-                out["caches"]["length"] = caches["length"] + 1
+            with jax.named_scope("head"):
+                if head:
+                    hF = norm_apply(cfg.norm_type, params["final_norm"], h)
+                    logits = _unembed(params, hF, cfg)[:, 0]
+                    ch = jnp.where(
+                        ex, ch, jnp.argmax(logits, -1).astype(jnp.int32)
+                    )
+                    out["caches"] = dict(out["caches"])
+                    out["caches"]["length"] = caches["length"] + 1
             if bucket is None:
                 out["exited"], out["chosen"] = ex, ch
                 out["take"], out["ents"] = take_s, ents_s
@@ -970,42 +991,49 @@ class TierExecutor:
                 elif degrade is None:
                     out["hidden"] = h
             else:
-                # ---- scatter back to original batch order (device-side).
-                nbr = len(branches)
-                out["exited"] = exited.at[rows].set(ex)
-                out["chosen"] = chosen.at[rows].set(ch)
-                out["take"] = (
-                    jnp.zeros((nbr, batch), bool).at[:, rows].set(take_s)
-                )
-                out["ents"] = (
-                    jnp.zeros((nbr, batch), jnp.float32).at[:, rows].set(ents_s)
-                )
-                pcols = rows if probe_m is None else rows[pr_idx]
-                out["ptake"] = (
-                    jnp.zeros((len(probe), batch), bool)
-                    .at[:, pcols].set(ptake_s)
-                )
-                out["pents"] = (
-                    jnp.zeros((len(probe), batch), jnp.float32)
-                    .at[:, pcols].set(pents_s)
-                )
-                if probe_m is not None:
-                    out["pcover"] = (
-                        jnp.zeros((batch,), bool).at[pcols].set(True)
+                with jax.named_scope("scatter"):
+                    # ---- scatter back to original batch order (device-side).
+                    nbr = len(branches)
+                    out["exited"] = exited.at[rows].set(ex)
+                    out["chosen"] = chosen.at[rows].set(ch)
+                    out["take"] = (
+                        jnp.zeros((nbr, batch), bool).at[:, rows].set(take_s)
                     )
-                if head:
-                    out["logits"] = (
-                        jnp.zeros((batch, logits.shape[-1]), logits.dtype)
-                        .at[rows].set(logits)
+                    out["ents"] = (
+                        jnp.zeros((nbr, batch), jnp.float32)
+                        .at[:, rows].set(ents_s)
                     )
-                elif degrade is None:
-                    out["hidden"] = (
-                        jnp.zeros((batch, 1, h.shape[-1]), h.dtype)
-                        .at[rows].set(h)
+                    pcols = rows if probe_m is None else rows[pr_idx]
+                    out["ptake"] = (
+                        jnp.zeros((len(probe), batch), bool)
+                        .at[:, pcols].set(ptake_s)
                     )
+                    out["pents"] = (
+                        jnp.zeros((len(probe), batch), jnp.float32)
+                        .at[:, pcols].set(pents_s)
+                    )
+                    if probe_m is not None:
+                        out["pcover"] = (
+                            jnp.zeros((batch,), bool).at[pcols].set(True)
+                        )
+                    if head:
+                        out["logits"] = (
+                            jnp.zeros((batch, logits.shape[-1]), logits.dtype)
+                            .at[rows].set(logits)
+                        )
+                    elif degrade is None:
+                        out["hidden"] = (
+                            jnp.zeros((batch, 1, h.shape[-1]), h.dtype)
+                            .at[rows].set(h)
+                        )
             return out
 
-        jitted = self._jit(fn)
+        name = f"decode_l{lo}_{hi}_b{'full' if bucket is None else bucket}"
+        if probe:
+            name += "_probe"
+        if degrade is not None:
+            name += f"_degrade{degrade}"
+        jitted = self._jit(fn, name)
         self._fn_cache[key] = jitted
         return jitted
 
@@ -1057,27 +1085,30 @@ class TierExecutor:
 
         Returns (new caches, first decode-step input token per prompt row
         (n,), device-resident — admission performs no host sync)."""
-        tokens = jnp.asarray(tokens, jnp.int32)
-        n, plen = tokens.shape
-        key = ("prefill", plen, n)
-        fn = self._fn_cache.get(key)
-        if fn is None:
-            cfg = self.cfg
-            trace_counts = self.trace_counts
+        with TraceAnnotation(spans.TIER_PREFILL):
+            tokens = jnp.asarray(tokens, jnp.int32)
+            n, plen = tokens.shape
+            key = ("prefill", plen, n)
+            fn = self._fn_cache.get(key)
+            if fn is None:
+                cfg = self.cfg
+                trace_counts = self.trace_counts
 
-            def prefill_fn(params, toks, rows_, caches_):
-                trace_counts[key] = trace_counts.get(key, 0) + 1
-                logits, new_caches = prefill(
-                    params, {"tokens": toks}, cfg, caches_, rows=rows_
-                )
-                tok0 = jnp.argmax(logits[:, 0], -1).astype(jnp.int32)
-                return tok0, new_caches
+                def prefill_fn(params, toks, rows_, caches_):
+                    trace_counts[key] = trace_counts.get(key, 0) + 1
+                    with jax.named_scope("prefill"):
+                        logits, new_caches = prefill(
+                            params, {"tokens": toks}, cfg, caches_,
+                            rows=rows_,
+                        )
+                        tok0 = jnp.argmax(logits[:, 0], -1).astype(jnp.int32)
+                    return tok0, new_caches
 
-            fn = self._jit(prefill_fn)
-            self._fn_cache[key] = fn
-        tok0, caches = fn(
-            self.params, tokens, jnp.asarray(rows, jnp.int32), caches
-        )
+                fn = self._jit(prefill_fn, f"prefill_p{plen}_n{n}")
+                self._fn_cache[key] = fn
+            tok0, caches = fn(
+                self.params, tokens, jnp.asarray(rows, jnp.int32), caches
+            )
         return caches, tok0
 
     def reset_rows(self, caches: Any, rows) -> Any:
@@ -1086,32 +1117,35 @@ class TierExecutor:
         (K/V payloads stay in place — unreachable once their slot is
         invalid).  Retirement hygiene; admission prefill also resets its
         row implicitly, so this is optional between occupants."""
-        rows = jnp.asarray(rows, jnp.int32)
-        key = ("reset", int(rows.shape[0]))
-        fn = self._fn_cache.get(key)
-        if fn is None:
+        with TraceAnnotation(spans.TIER_RESET):
+            rows = jnp.asarray(rows, jnp.int32)
+            n = int(rows.shape[0])
+            key = ("reset", n)
+            fn = self._fn_cache.get(key)
+            if fn is None:
 
-            def reset_fn(caches_, rows_):
-                def walk(tree):
-                    out = {}
-                    for k, v in tree.items():
-                        if isinstance(v, dict):
-                            out[k] = walk(v)
-                        elif k == "pos":
-                            out[k] = v.at[:, rows_].set(-1, mode="drop")
-                        elif k in ("conv", "ssm"):
-                            out[k] = v.at[:, rows_].set(
-                                jnp.zeros((), v.dtype), mode="drop"
-                            )
-                        else:
-                            out[k] = v
-                    return out
+                def reset_fn(caches_, rows_):
+                    def walk(tree):
+                        out = {}
+                        for k, v in tree.items():
+                            if isinstance(v, dict):
+                                out[k] = walk(v)
+                            elif k == "pos":
+                                out[k] = v.at[:, rows_].set(-1, mode="drop")
+                            elif k in ("conv", "ssm"):
+                                out[k] = v.at[:, rows_].set(
+                                    jnp.zeros((), v.dtype), mode="drop"
+                                )
+                            else:
+                                out[k] = v
+                        return out
 
-                return walk(caches_)
+                    return walk(caches_)
 
-            fn = jax.jit(reset_fn)
-            self._fn_cache[key] = fn
-        return fn(caches, rows)
+                reset_fn.__name__ = reset_fn.__qualname__ = f"reset_rows_n{n}"
+                fn = jax.jit(reset_fn)
+                self._fn_cache[key] = fn
+            return fn(caches, rows)
 
     # -------------------------------------------------------------- step
     def _plan_buckets(self, batch: int) -> dict[int, int]:
@@ -1221,7 +1255,6 @@ class TierExecutor:
     def _run_once(
         self, tok: jax.Array, pos, caches: Any, buckets: dict[int, int],
         probe_map: dict[int, tuple[int, ...]] | None = None,
-        exited0: jax.Array | None = None,
         probe_rows: jax.Array | None = None,
         probe_m: int | None = None,
         active_np: np.ndarray | None = None,
@@ -1230,19 +1263,20 @@ class TierExecutor:
         """Dispatch all tier segments and perform the single host sync.
         Returns (host dict, caches, entering-survivor counts per segment,
         chosen, logits, alive-after-segment counts, plan-exit mask).
-        ``exited0`` seeds the exit mask with the dead slots of a
+        ``active_np`` seeds the exit mask with the dead slots of a
         continuous-batching step (they compact away downstream exactly
         like early exits).  ``degrade=(seg_idx, layer)`` truncates the
         step at ``seg_idx``, whose fn force-finalizes survivors from the
         exit head at ``layer`` (broken-hop fallback; no head tier runs)."""
         probe_map = probe_map or {}
-        cfg = self.cfg
         batch = tok.shape[0]
-        posj = jnp.asarray(pos, jnp.int32)
-        exited = (
-            jnp.zeros((batch,), bool) if exited0 is None else exited0
-        )
-        chosen = jnp.zeros((batch,), jnp.int32)
+        with TraceAnnotation(spans.TIER_STAGE):
+            posj = jnp.asarray(pos, jnp.int32)
+            exited = (
+                jnp.zeros((batch,), bool) if active_np is None
+                else jnp.asarray(~active_np)
+            )
+            chosen = jnp.zeros((batch,), jnp.int32)
         x: jax.Array = tok
         fetch: dict[str, Any] = {}
         logits = None
@@ -1260,24 +1294,31 @@ class TierExecutor:
                 degrade[1] if degrade is not None and i == degrade[0]
                 else None
             )
-            if b is None and not pr and deg is None:
-                fn = self._fns[i]
-            else:
-                # Downstream tiers always run the compact->run->scatter fn
-                # in bucketed mode — even at bucket == batch — so exited
-                # rows' downstream cache writes are always dropped and KV
-                # validity stays a pure function of exits, never of which
-                # fn variant a hint happened to select.
-                fn = self._segment_fn(
-                    seg, head, None if b is None else min(b, batch), probe=pr,
-                    probe_m=probe_m if pr else None, degrade=deg,
-                )
-            if pr and probe_m is not None:
-                out = fn(
-                    self.params, x, posj, exited, chosen, caches, probe_rows
-                )
-            else:
-                out = fn(self.params, x, posj, exited, chosen, caches)
+            with TraceAnnotation(
+                spans.TIER_DISPATCH, seg=i,
+                bucket=batch if b is None else min(b, batch),
+            ):
+                if b is None and not pr and deg is None:
+                    fn = self._fns[i]
+                else:
+                    # Downstream tiers always run the compact->run->scatter
+                    # fn in bucketed mode — even at bucket == batch — so
+                    # exited rows' downstream cache writes are always
+                    # dropped and KV validity stays a pure function of
+                    # exits, never of which fn variant a hint happened to
+                    # select.
+                    fn = self._segment_fn(
+                        seg, head, None if b is None else min(b, batch),
+                        probe=pr, probe_m=probe_m if pr else None,
+                        degrade=deg,
+                    )
+                if pr and probe_m is not None:
+                    out = fn(
+                        self.params, x, posj, exited, chosen, caches,
+                        probe_rows,
+                    )
+                else:
+                    out = fn(self.params, x, posj, exited, chosen, caches)
             caches = out["caches"]
             exited, chosen = out["exited"], out["chosen"]
             if seg.branches:
@@ -1298,7 +1339,8 @@ class TierExecutor:
 
         fetch["tokens"] = chosen
         fetch["exited"] = exited
-        host = jax.device_get(fetch)  # the step's single device->host sync
+        with TraceAnnotation(spans.TIER_SYNC):
+            host = jax.device_get(fetch)  # the step's single sync
         self.host_syncs += 1
 
         # Host-side bookkeeping on the fetched masks (no further syncs):
@@ -1307,21 +1349,22 @@ class TierExecutor:
         # degraded step segments past the truncation have no masks; their
         # counts carry the last executed segment's survivors (the rows the
         # fallback head force-finalized).
-        exited_run = (
-            np.zeros((batch,), bool) if active_np is None
-            else ~np.asarray(active_np, bool)
-        )
-        alive_after_seg = {}
-        for i, seg in enumerate(self.segments):
-            for row, _layer in enumerate(seg.branches):
-                if f"take{i}" in host:
-                    exited_run |= host[f"take{i}"][row]
-            alive_after_seg[i] = int(batch - exited_run.sum())
-        entering = {
-            i: alive_after_seg[i - 1]
-            for i in range(1, last_idx)
-            if not self.segments[i].is_empty
-        }
+        with TraceAnnotation(spans.TIER_ACCOUNT):
+            exited_run = (
+                np.zeros((batch,), bool) if active_np is None
+                else ~np.asarray(active_np, bool)
+            )
+            alive_after_seg = {}
+            for i, seg in enumerate(self.segments):
+                for row, _layer in enumerate(seg.branches):
+                    if f"take{i}" in host:
+                        exited_run |= host[f"take{i}"][row]
+                alive_after_seg[i] = int(batch - exited_run.sum())
+            entering = {
+                i: alive_after_seg[i - 1]
+                for i in range(1, last_idx)
+                if not self.segments[i].is_empty
+            }
         return host, caches, entering, chosen, logits, alive_after_seg, \
             exited_run
 
@@ -1336,6 +1379,12 @@ class TierExecutor:
         ``active`` (B,) bool marks live request slots: dead slots enter the
         step pre-exited — the entry tier masks them, downstream tiers
         compact them away, and they never count as survivors or ship."""
+        with TraceAnnotation(spans.TIER_STEP):
+            return self._step(tok, pos, caches, active)
+
+    def _step(
+        self, tok: jax.Array, pos, caches: Any, active
+    ) -> tuple[TierStepResult, Any]:
         cfg = self.cfg
         batch = tok.shape[0]
         # Snapshot (never alias) the caller's mask: the scheduler mutates
@@ -1343,7 +1392,6 @@ class TierExecutor:
         # its on_step/controller consumers — must keep the mask the step
         # actually ran with.
         active_np = None if active is None else np.array(active, dtype=bool)
-        exited0 = None if active_np is None else jnp.asarray(~active_np)
         live = batch if active_np is None else int(active_np.sum())
         # ---- fault plane, phase A: decide hop health before dispatch.
         broken: int | None = None
@@ -1351,7 +1399,8 @@ class TierExecutor:
         fault_events: tuple[FaultEvent, ...] = ()
         degrade: tuple[int, int] | None = None
         if self.fault_model is not None:
-            broken, outcomes, fault_events = self._plan_hops(batch)
+            with TraceAnnotation(spans.TIER_PLAN):
+                broken, outcomes, fault_events = self._plan_hops(batch)
             self.fault_step += 1
             if broken is not None:
                 self.degraded_steps += 1
@@ -1419,39 +1468,41 @@ class TierExecutor:
                 degraded_hop=broken,
             )
             return result, caches
-        probe_map = self._probe_layers() if self.probe_next else {}
-        self.probe_next = False
-        probe_rows = None
-        probe_m = None
-        if probe_map and self.probe_sample_frac < 1.0:
-            pool = (
-                np.flatnonzero(active_np)
-                if active_np is not None and active_np.any()
-                else np.arange(batch)
-            )
-            # Sample size: the configured fraction of the nominal batch,
-            # capped at the live pool (no duplicate rows burning head
-            # FLOPs at low occupancy) and floored to the bucket ladder so
-            # the probe-fn shape set stays bounded as occupancy drifts.
-            want = min(
-                max(1, math.ceil(self.probe_sample_frac * batch)), len(pool)
-            )
-            m = max(
-                b for b in bucket_ladder(batch) if b <= want
-            )
-            if m < batch:
-                # Deterministic rotation over the live rows: successive
-                # probes cycle the pool so every row's entropy gets
-                # sampled without an RNG in the hot loop.
-                sel = pool[(self._probe_offset + np.arange(m)) % len(pool)]
-                self._probe_offset = (self._probe_offset + m) % len(pool)
-                probe_rows = jnp.asarray(sel, jnp.int32)
-                probe_m = m
-        buckets = self._plan_buckets(batch)
+        with TraceAnnotation(spans.TIER_PLAN):
+            probe_map = self._probe_layers() if self.probe_next else {}
+            self.probe_next = False
+            probe_rows = None
+            probe_m = None
+            if probe_map and self.probe_sample_frac < 1.0:
+                pool = (
+                    np.flatnonzero(active_np)
+                    if active_np is not None and active_np.any()
+                    else np.arange(batch)
+                )
+                # Sample size: the configured fraction of the nominal batch,
+                # capped at the live pool (no duplicate rows burning head
+                # FLOPs at low occupancy) and floored to the bucket ladder so
+                # the probe-fn shape set stays bounded as occupancy drifts.
+                want = min(
+                    max(1, math.ceil(self.probe_sample_frac * batch)),
+                    len(pool),
+                )
+                m = max(
+                    b for b in bucket_ladder(batch) if b <= want
+                )
+                if m < batch:
+                    # Deterministic rotation over the live rows: successive
+                    # probes cycle the pool so every row's entropy gets
+                    # sampled without an RNG in the hot loop.
+                    sel = pool[(self._probe_offset + np.arange(m)) % len(pool)]
+                    self._probe_offset = (self._probe_offset + m) % len(pool)
+                    probe_rows = jnp.asarray(sel, jnp.int32)
+                    probe_m = m
+            buckets = self._plan_buckets(batch)
         host, new_caches, entering, chosen, logits, alive, exited_plan = \
             self._run_once(
                 tok, pos, caches, buckets, probe_map,
-                exited0, probe_rows, probe_m, active_np, degrade,
+                probe_rows, probe_m, active_np, degrade,
             )
         used = {
             i: min(buckets.get(i, batch), batch) for i in entering
@@ -1469,83 +1520,85 @@ class TierExecutor:
         # falls back to guaranteed-fit full-batch buckets.
         attempts = 0
         while any(entering[i] > used[i] for i in entering):
-            self.overflow_retries += 1
-            attempts += 1
-            if attempts >= len(self.segments):
-                buckets = {i: batch for i in entering}
-            else:
-                buckets = {
-                    i: max(
-                        min(buckets.get(i, 1), batch),
-                        bucket_for(entering[i], batch),
-                    )
-                    for i in entering
-                }
-            host, new_caches, entering, chosen, logits, alive, exited_plan = \
-                self._run_once(
+            with TraceAnnotation(spans.TIER_RETRY):
+                self.overflow_retries += 1
+                attempts += 1
+                if attempts >= len(self.segments):
+                    buckets = {i: batch for i in entering}
+                else:
+                    buckets = {
+                        i: max(
+                            min(buckets.get(i, 1), batch),
+                            bucket_for(entering[i], batch),
+                        )
+                        for i in entering
+                    }
+                (host, new_caches, entering, chosen, logits, alive,
+                 exited_plan) = self._run_once(
                     tok, pos, caches, buckets, probe_map,
-                    exited0, probe_rows, probe_m, active_np, degrade,
+                    probe_rows, probe_m, active_np, degrade,
                 )
-            used = {i: min(buckets.get(i, batch), batch) for i in entering}
-        self._observe_hints(entering)
+                used = {i: min(buckets.get(i, batch), batch) for i in entering}
+        with TraceAnnotation(spans.TIER_ACCOUNT):
+            self._observe_hints(entering)
 
-        # Per-branch attribution from the fetched masks.  Probe branches
-        # report would-exit masks/entropies only — they never touch
-        # exit_tier (the trajectory is that of a normal step).
-        exit_tier = np.full((batch,), -1, np.int32)
-        branch_take: dict[int, np.ndarray] = {}
-        branch_entropy: dict[int, np.ndarray] = {}
-        branch_probe_mask: dict[int, np.ndarray] = {}
-        for i, seg in enumerate(self.segments):
-            for row, layer in enumerate(seg.branches):
-                if f"take{i}" not in host:  # truncated degraded step
+            # Per-branch attribution from the fetched masks.  Probe branches
+            # report would-exit masks/entropies only — they never touch
+            # exit_tier (the trajectory is that of a normal step).
+            exit_tier = np.full((batch,), -1, np.int32)
+            branch_take: dict[int, np.ndarray] = {}
+            branch_entropy: dict[int, np.ndarray] = {}
+            branch_probe_mask: dict[int, np.ndarray] = {}
+            for i, seg in enumerate(self.segments):
+                for row, layer in enumerate(seg.branches):
+                    if f"take{i}" not in host:  # truncated degraded step
+                        continue
+                    mask = host[f"take{i}"][row]
+                    branch_take[layer] = mask
+                    branch_entropy[layer] = host[f"ents{i}"][row]
+                    exit_tier[mask] = i
+                for row, layer in enumerate(probe_map.get(i, ())):
+                    if f"ptake{i}" not in host:
+                        continue
+                    branch_take[layer] = host[f"ptake{i}"][row]
+                    branch_entropy[layer] = host[f"pents{i}"][row]
+                    if probe_m is not None:
+                        branch_probe_mask[layer] = host[f"pcover{i}"]
+
+            # Degraded rows: exited in the fetch but not through any plan
+            # branch — the fallback head force-finalized them.  Their tokens
+            # are real (the fallback head's argmax); ``exit_tier`` points at
+            # the fallback tier; they are deliberately NOT added to
+            # ``branch_take`` so exit-probability estimates see only genuine
+            # threshold exits.
+            degraded_mask = None
+            failed_mask = None
+            if broken is not None:
+                degraded_mask = np.asarray(host["exited"], bool) & ~exited_plan
+                exit_tier[degraded_mask] = degrade[0]
+                failed_mask = np.zeros((batch,), bool)
+
+            # Hops: one per cut that still has layers (or the head) downstream.
+            # A degraded step truncates at the fallback tier, so hops from it
+            # onward carried nothing (phase A burned their retry overhead
+            # pre-flight; no payload ever reached the broken link).
+            stop_hop = self._head_idx if degrade is None else degrade[0]
+            shipped, nbytes, compaction = [], [], []
+            for j in range(self._head_idx):
+                if j >= stop_hop:
+                    shipped.append(0)
+                    nbytes.append(0.0)
+                    compaction.append(HopCompaction(0, 0))
                     continue
-                mask = host[f"take{i}"][row]
-                branch_take[layer] = mask
-                branch_entropy[layer] = host[f"ents{i}"][row]
-                exit_tier[mask] = i
-            for row, layer in enumerate(probe_map.get(i, ())):
-                if f"ptake{i}" not in host:
-                    continue
-                branch_take[layer] = host[f"ptake{i}"][row]
-                branch_entropy[layer] = host[f"pents{i}"][row]
-                if probe_m is not None:
-                    branch_probe_mask[layer] = host[f"pcover{i}"]
-
-        # Degraded rows: exited in the fetch but not through any plan
-        # branch — the fallback head force-finalized them.  Their tokens
-        # are real (the fallback head's argmax); ``exit_tier`` points at
-        # the fallback tier; they are deliberately NOT added to
-        # ``branch_take`` so exit-probability estimates see only genuine
-        # threshold exits.
-        degraded_mask = None
-        failed_mask = None
-        if broken is not None:
-            degraded_mask = np.asarray(host["exited"], bool) & ~exited_plan
-            exit_tier[degraded_mask] = degrade[0]
-            failed_mask = np.zeros((batch,), bool)
-
-        # Hops: one per cut that still has layers (or the head) downstream.
-        # A degraded step truncates at the fallback tier, so hops from it
-        # onward carried nothing (phase A burned their retry overhead
-        # pre-flight; no payload ever reached the broken link).
-        stop_hop = self._head_idx if degrade is None else degrade[0]
-        shipped, nbytes, compaction = [], [], []
-        for j in range(self._head_idx):
-            if j >= stop_hop:
-                shipped.append(0)
-                nbytes.append(0.0)
-                compaction.append(HopCompaction(0, 0))
-                continue
-            cut = self.segments[j].layer_hi
-            alive_j = alive[j]
-            shipped.append(alive_j)
-            nbytes.append(alive_j * bytes_per_sequence(cfg, cut))
-            nxt = next(
-                i for i in range(j + 1, len(self.segments))
-                if not self.segments[i].is_empty
-            )
-            compaction.append(HopCompaction(alive_j, used.get(nxt, batch)))
+                cut = self.segments[j].layer_hi
+                alive_j = alive[j]
+                shipped.append(alive_j)
+                nbytes.append(alive_j * bytes_per_sequence(cfg, cut))
+                nxt = next(
+                    i for i in range(j + 1, len(self.segments))
+                    if not self.segments[i].is_empty
+                )
+                compaction.append(HopCompaction(alive_j, used.get(nxt, batch)))
 
         sim = ()
         if self.simulate_network:
