@@ -104,7 +104,7 @@ def _kernel(
 
 
 @functools.partial(
-    jax.jit, static_argnames=("window", "block_c", "interpret")
+    jax.jit, static_argnames=("window", "block_c", "scale", "interpret")
 )
 def flash_decode_pallas(
     q: jax.Array,  # (B, H, D)
@@ -116,14 +116,16 @@ def flash_decode_pallas(
     *,
     window: int = 0,
     block_c: int = 128,
+    scale: float | None = None,
     interpret: bool = False,
 ) -> jax.Array:
     """``block_c`` must be a multiple of 128 (the lane width the slot
-    positions are tiled on)."""
+    positions are tiled on).  ``scale`` defaults to ``1 / sqrt(D)``; a
+    head_dim zero-padded to the lanes passes its true head_dim's."""
     b, h, d = q.shape
     bc, c, kh, _ = k.shape
     g = h // kh
-    scale = 1.0 / np.sqrt(d)
+    scale = 1.0 / np.sqrt(d) if scale is None else scale
 
     if k_pos.ndim == 1:
         k_pos = jnp.broadcast_to(k_pos, (bc, c))

@@ -107,11 +107,12 @@ def entropy_exit_argmax_heads(logits, thresholds, *,
 
 
 def flash_decode(q, k, v, k_pos, q_pos, rows=None, *, window: int = 0,
-                 interpret: bool | None = None):
+                 scale: float | None = None, interpret: bool | None = None):
     """Single-token GQA decode attention against a (ring) KV cache.
-    ``rows`` maps a compacted survivor sub-batch onto cache rows."""
+    ``rows`` maps a compacted survivor sub-batch onto cache rows;
+    ``scale`` overrides ``1 / sqrt(D)`` (a lane-padded head_dim)."""
     return flash_decode_pallas(q, k, v, k_pos, q_pos, rows, window=window,
-                               interpret=_interpret(interpret))
+                               scale=scale, interpret=_interpret(interpret))
 
 
 def ssd_scan(x, a, b_mat, c_mat, *, chunk: int = 128,

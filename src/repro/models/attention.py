@@ -14,6 +14,11 @@ KV cache layout (dict):
     pos:  (B, C) int32    — absolute position stored in each slot, -1 empty
     length: () int32      — tokens decoded so far (write index = length % C)
 
+k and v may hold head_dim zero-padded to the TPU's 128 lanes (D' > D,
+:func:`repro.models.model.pad_kv_lanes`), so that the TPU's default layout
+of the cache is the one the decode kernel and the in-place slot writes
+read; attention pads the new K/V and q to D' and slices its output back.
+
 ``pos`` is per *sequence*: in the survivor-compacted tier runtime an
 early-exited sequence skips the downstream tiers for that step, so its
 slot stays -1 (a hole) while survivors' slots go valid — attention then
@@ -328,26 +333,26 @@ def _cache_prefill(cache: Params, k: jax.Array, v: jax.Array) -> Params:
     }
 
 
-def _cache_prefill_rows(
-    cache: Params, k: jax.Array, v: jax.Array, rows: jax.Array
-) -> Params:
-    """Row-targeted prompt prefill: write rows ``rows`` of the resident
-    full-batch cache as if each were a *freshly initialized* cache that
-    just prefilled this prompt — slots past the prompt reset to empty
-    (pos = -1), so no stale K/V from the row's previous occupant can ever
-    look valid.  Other rows (and the resident step counter) are untouched;
-    OOB sentinel rows (admission-group padding) drop their writes."""
+def _cache_prefill_rows(cache: Params, k: jax.Array, v: jax.Array) -> Params:
+    """Row-targeted prompt prefill: the (n, C, ...) row block that the
+    admitted rows of the resident cache take — each row exactly a
+    *freshly initialized* cache that just prefilled its prompt, slots past
+    the prompt empty (pos = -1), so no stale K/V from the row's previous
+    occupant can ever look valid.  The layer stack writes the block into
+    its rows in place (:func:`repro.models.transformer.write_row_blocks`;
+    OOB sentinel rows of admission-group padding drop); the resident cache
+    is neither read nor rewritten whole, and its step counter is kept."""
     fresh = _cache_prefill(
         init_kv_cache(k.shape[0], cache["k"].shape[1], k.shape[2], k.shape[3],
                       cache["k"].dtype),
         k, v,
     )
-    return {
-        "k": cache["k"].at[rows].set(fresh["k"], mode="drop"),
-        "v": cache["v"].at[rows].set(fresh["v"], mode="drop"),
-        "pos": cache["pos"].at[rows].set(fresh["pos"], mode="drop"),
-        "length": cache["length"],
-    }
+    return {**fresh, "length": cache["length"]}
+
+
+def _pad_lanes(x: jax.Array, width: int) -> jax.Array:
+    """Zero-pad the last axis of ``x`` to ``width``."""
+    return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, width - x.shape[-1])])
 
 
 # ============================================================== standard GQA
@@ -422,24 +427,35 @@ def attn_apply(
             k = apply_rope(k, positions, cfg.rope_theta)
 
     qg = q.reshape(b, s, kh, g, hd)
+    if cache is not None:
+        # Lane-padded storage (module docstring): K/V written at the
+        # cache's head_dim, the extra lanes zero.
+        dp = cache["k"].shape[-1]
+        kc, vc = (k, v) if dp == hd else (_pad_lanes(k, dp), _pad_lanes(v, dp))
 
     if cache is not None and s > 1:
         # -------- prefill with cache write-through: full-sequence attention
         # plus populating the (ring) cache for subsequent decode steps.
-        # ``rows`` targets the write at those rows of the resident
-        # full-batch cache (continuous-batching admission); the prompt's
-        # attention itself never reads the cache, so it is identical to a
-        # fresh solo prefill by construction.
+        # ``rows`` (continuous-batching admission) returns the admitted
+        # rows' block for the layer stack to write in place; the
+        # prompt's attention itself never reads the cache, so it is
+        # identical to a fresh solo prefill by construction.
         new_cache = (
-            _cache_prefill(cache, k, v) if rows is None
-            else _cache_prefill_rows(cache, k, v, rows)
+            _cache_prefill(cache, kc, vc) if rows is None
+            else _cache_prefill_rows(cache, kc, vc)
         )
         out = flash_attention(
             qg, k, v, positions, positions, window=window, block_k=min(1024, s)
         )
     elif cache is not None:
         # -------- decode: write this step, attend over the whole cache.
-        cache = _cache_write(cache, k, v, rows, positions)
+        cache = _cache_write(cache, kc, vc, rows, positions)
+        # A lane-padded cache: q gains zero lanes (the scores are those of
+        # the true head_dim, which sets the scale) and the output loses
+        # them.
+        scale = None if dp == hd else 1.0 / np.sqrt(hd)
+        if dp != hd:
+            qg = _pad_lanes(qg, dp)
         if cfg.decode_qhd_shard:
             # Run attention in the cache's head-dim-sharded layout: scores
             # become partial sums (all-reduce) instead of resharding the
@@ -453,10 +469,10 @@ def attn_apply(
             # ride the same scalar-prefetch path as a (B,) vector.
             q_pos = positions[:, 0] if positions.ndim == 2 else positions[0]
             out = kernel_ops.flash_decode(
-                qg.reshape(b, kh * g, hd),
+                qg.reshape(b, kh * g, dp),
                 cache["k"], cache["v"], cache["pos"], q_pos,
-                rows, window=window,
-            ).reshape(b, 1, kh, g, hd)
+                rows, window=window, scale=scale,
+            ).reshape(b, 1, kh, g, dp)
         else:
             if rows is None:
                 ck, cv, cp = cache["k"], cache["v"], cache["pos"]
@@ -469,8 +485,9 @@ def attn_apply(
                 )
             out = flash_attention(
                 qg, ck, cv, positions, cp,
-                window=window, block_k=min(1024, ck.shape[1]),
+                window=window, block_k=min(1024, ck.shape[1]), scale=scale,
             )
+        out = out[..., :hd]
         new_cache = cache
     elif kv_override is not None:
         # -------- cross-attention: bidirectional over encoder frames.
@@ -565,22 +582,17 @@ def _mla_prefill_cache(
 
 
 def _mla_prefill_rows(
-    cache: Params, ckv: jax.Array, k_rope: jax.Array, rows: jax.Array, cfg
+    cache: Params, ckv: jax.Array, k_rope: jax.Array, cfg
 ) -> Params:
     """Row-targeted MLA prompt prefill (see :func:`_cache_prefill_rows`):
-    each target row ends exactly as a fresh solo prefill — tail slots reset
-    to empty — and the resident step counter is untouched."""
+    the admitted rows' block, each row exactly a fresh solo prefill —
+    tail slots empty — with the resident step counter kept."""
     fresh = _mla_prefill_cache(
         init_mla_cache(ckv.shape[0], cache["ckv"].shape[1], cfg,
                        cache["ckv"].dtype),
         ckv, k_rope,
     )
-    return {
-        "ckv": cache["ckv"].at[rows].set(fresh["ckv"], mode="drop"),
-        "k_rope": cache["k_rope"].at[rows].set(fresh["k_rope"], mode="drop"),
-        "pos": cache["pos"].at[rows].set(fresh["pos"], mode="drop"),
-        "length": cache["length"],
-    }
+    return {**fresh, "length": cache["length"]}
 
 
 def _mla_qkr(params, x, cfg, positions):
@@ -638,10 +650,10 @@ def mla_apply(
         new_cache = None
         if cache is not None:
             # Prefill write-through of the latent cache (ring invariant);
-            # ``rows`` targets admitted rows of the resident cache.
+            # ``rows``: the admitted rows' block (see _cache_prefill_rows).
             new_cache = (
                 _mla_prefill_cache(cache, ckv, k_rope) if rows is None
-                else _mla_prefill_rows(cache, ckv, k_rope, rows, cfg)
+                else _mla_prefill_rows(cache, ckv, k_rope, cfg)
             )
     else:
         # Absorbed decode: score and read directly in the latent space.

@@ -187,8 +187,9 @@ def mamba_apply(
     ``rows[i]`` of the full-batch recurrent state (other rows untouched).
     With ``s > 1`` (continuous-batching admission) x is a block of newly
     admitted prompts: the scan starts from a *fresh zero* state — exactly
-    a solo prefill — and the resulting conv window / SSM state scatter
-    into rows ``rows`` of the resident state in place.
+    a solo prefill — and the resulting conv window / SSM state come back
+    as the admitted rows' block, which the layer stack writes into rows
+    ``rows`` of the resident state in place.
 
     ``use_kernels`` (decode only): the recurrent step runs in the Pallas
     ssd_update kernel, which reads the survivor rows of the full-batch
@@ -264,14 +265,12 @@ def mamba_apply(
                 raw_xbc, ((0, 0), (max(0, (w - 1) - s), 0), (0, 0))
             )[:, -(w - 1) :, :]
             if prefill_rows:
-                # Scatter the admitted rows into the resident state; the
-                # resident step counter is untouched (mode="drop" skips
-                # admission-group padding rows' OOB sentinels).
+                # The admitted rows' block, which the layer stack writes
+                # into the resident state in place (models.transformer
+                # .run_stack); the resident step counter is untouched.
                 new_state = {
-                    "conv": full_state["conv"].at[rows].set(
-                        conv_tail.astype(full_state["conv"].dtype), mode="drop"
-                    ),
-                    "ssm": full_state["ssm"].at[rows].set(h_last, mode="drop"),
+                    "conv": conv_tail.astype(full_state["conv"].dtype),
+                    "ssm": h_last.astype(full_state["ssm"].dtype),
                     "length": full_state["length"],
                 }
             else:

@@ -52,12 +52,16 @@ from repro.models.transformer import (
     run_stack,
     stack_init,
     stack_slice,
+    write_row_blocks,
 )
 
 __all__ = [
     "branch_logits_stacked",
     "init_params",
     "init_caches",
+    "pad_kv_lanes",
+    "snapshot_decode_writes",
+    "restore_decode_writes",
     "run_trunk",
     "forward_train",
     "prefill",
@@ -182,6 +186,106 @@ def init_caches(
     return caches
 
 
+#: The TPU's lane width: the minor dimension of its (8, 128) memory tiles.
+LANES = 128
+
+
+def pad_kv_lanes(caches: Params) -> Params:
+    """``caches`` with each attention cache's K/V head_dim zero-padded to
+    a multiple of :data:`LANES` (unchanged where it already is one).
+
+    The TPU lays a cache out by its shape: with a head_dim of 96 (phi3)
+    its default layout puts the slots in the lanes, while the decode
+    kernel and the in-place slot writes read head_dim there, so every
+    program that takes the cache would re-lay it out whole on entry and
+    exit.  Padded, the default layout is the one they read (the same
+    bytes the TPU's tiling pads 96 to anyway).  Attention pads what it
+    writes and slices what it reads (:mod:`repro.models.attention`)."""
+
+    def walk(tree):
+        if not isinstance(tree, dict):
+            return tree
+        if {"k", "v", "pos"} <= tree.keys():
+            d = tree["k"].shape[-1]
+            pad = [(0, 0)] * (tree["k"].ndim - 1) + [(0, -d % LANES)]
+            return {**tree, "k": jnp.pad(tree["k"], pad),
+                    "v": jnp.pad(tree["v"], pad)}
+        return {k: walk(v) for k, v in tree.items()}
+
+    return walk(caches)
+
+
+#: Leaves of a slotted cache (one that carries per-slot ``pos``) laid out
+#: (L, B, C, ...): a decode step writes one slot of each row per layer.
+_SLOT_LEAVES = ("k", "v", "pos", "ckv", "k_rope")
+
+
+def snapshot_decode_writes(caches: Params, pos: jax.Array) -> Params:
+    """Everything one decode step at ``pos`` can write, read before it
+    writes: slot ``pos % C`` of every row in every layer of each slotted
+    cache (slot ``length % C`` per layer for a shared () ``pos``), and
+    whole every other leaf a step rewrites (``length``, the recurrent
+    ``conv`` / ``ssm`` state).  Encoder cross K/V is never written and is
+    left out.  About 4 MB for phi3-mini's 32 layers x 8 slots, against a
+    2 GB cache; :func:`restore_decode_writes` puts it back."""
+
+    def walk(tree):
+        out = {}
+        for key, val in tree.items():
+            if key == "cross_kv":
+                continue
+            if isinstance(val, dict) and "pos" in val:
+                n, b, c = val["pos"].shape
+                slot = (
+                    jnp.broadcast_to(val["length"][:, None] % c, (n, b))
+                    if pos.ndim == 0
+                    else jnp.broadcast_to(pos.astype(jnp.int32) % c, (n, b))
+                )
+                out[key] = {"slot": slot}
+                for k, a in val.items():
+                    if k in _SLOT_LEAVES:
+                        idx = slot.reshape(n, b, 1, *([1] * (a.ndim - 3)))
+                        out[key][k] = jnp.take_along_axis(a, idx, axis=2)
+                    else:
+                        out[key][k] = a
+            elif isinstance(val, dict):
+                out[key] = walk(val)
+            else:
+                out[key] = val
+        return out
+
+    return walk(caches)
+
+
+def restore_decode_writes(caches: Params, snap: Params) -> Params:
+    """``caches`` as they were when ``snap`` was taken from them
+    (:func:`snapshot_decode_writes`), bitwise, provided only the one
+    decode step ``snap`` was taken before has written to them since."""
+
+    def walk(tree, saved):
+        out = {}
+        for key, val in tree.items():
+            if key not in saved:
+                out[key] = val
+            elif isinstance(val, dict) and "slot" in saved[key]:
+                slot = saved[key]["slot"]
+                n, b = slot.shape
+                li = jnp.arange(n)[:, None]
+                bi = jnp.arange(b)[None, :]
+                out[key] = {
+                    k: (a.at[li, bi, slot].set(saved[key][k][:, :, 0])
+                        if k in _SLOT_LEAVES else saved[key][k])
+                    for k, a in val.items()
+                }
+            elif isinstance(val, dict):
+                out[key] = walk(val, saved[key])
+            else:
+                out[key] = saved[key]
+        return out
+
+    return walk(caches, snap)
+
+
 # ---------------------------------------------------------------- trunk
 def _segments(breaks: list[int], lo: int, hi: int) -> list[tuple[int, int]]:
     pts = sorted({lo, hi, *[b for b in breaks if lo < b < hi]})
@@ -271,9 +375,17 @@ def run_trunk(
             )
             aux = aux + a
             if nc is not None and caches is not None:
-                new_caches["shared_attn"] = jax.tree_util.tree_map(
-                    lambda full, one: full.at[site_idx].set(one),
-                    new_caches["shared_attn"], nc,
+                new_caches["shared_attn"] = (
+                    write_row_blocks(
+                        new_caches["shared_attn"],
+                        jax.tree_util.tree_map(lambda a: a[None], nc),
+                        site_idx, rows,
+                    )
+                    if rows is not None and h.shape[1] > 1
+                    else jax.tree_util.tree_map(
+                        lambda full, one: full.at[site_idx].set(one),
+                        new_caches["shared_attn"], nc,
+                    )
                 )
 
         if seg_hi in collect:

@@ -37,6 +37,7 @@ __all__ = [
     "stack_slice",
     "run_stack",
     "init_block_cache",
+    "write_row_blocks",
 ]
 
 Params = dict
@@ -173,6 +174,33 @@ def block_apply(
     return h, (new_cache if new_cache else None), aux
 
 
+def write_row_blocks(
+    full: Params, blocks: Params, layer, rows: jax.Array
+) -> Params:
+    """Write a row-targeted prompt prefill's blocks into the stacked cache
+    ``full`` in place: ``blocks`` leaves are (Lb, n, ...) — layers
+    ``[layer, layer + Lb)`` of the admitted rows — and row ``j`` of them
+    lands in row ``rows[j]`` (an OOB sentinel row of admission-group
+    padding writes back what it reads: a no-op).  The resident cache is
+    never read or rewritten whole, and its step counters are kept."""
+
+    def write(path, a, b):
+        if path[-1].key == "length":
+            return a
+        b = b.astype(a.dtype)
+        zeros = (0,) * (a.ndim - 2)
+        for j in range(b.shape[1]):
+            ok = (rows[j] >= 0) & (rows[j] < a.shape[1])
+            start = (layer, jnp.where(ok, rows[j], 0), *zeros)
+            old = jax.lax.dynamic_slice(a, start, (b.shape[0], 1, *a.shape[2:]))
+            a = jax.lax.dynamic_update_slice(
+                a, jnp.where(ok, b[:, j : j + 1], old), start
+            )
+        return a
+
+    return jax.tree_util.tree_map_with_path(write, full, blocks)
+
+
 def stack_init(key, cfg: ModelConfig, n_layers: int, kind: BlockKind) -> Params:
     """Stacked params: every leaf gains a leading (n_layers,) axis."""
     keys = jax.random.split(key, n_layers)
@@ -245,6 +273,28 @@ def run_stack(
 
     def pick(i):
         return lambda a: jax.lax.dynamic_index_in_dim(a, i, 0, keepdims=False)
+
+    if rows is not None and h.shape[1] > 1:
+        # Row-targeted prompt prefill (admission): the prompt's attention
+        # reads no cache, so the scan carries only the residual stream and
+        # emits each layer's block of the admitted rows, which lands in
+        # the resident cache in place after the scan — the cache is never
+        # carried through the loop, read, or rewritten whole.
+        def body_rows(h, i):
+            # Shapes and dtypes only: a layer's block never reads them.
+            like = jax.tree_util.tree_map(
+                lambda a: jnp.zeros(a.shape[1:], a.dtype), caches
+            )
+            h, block, aux = block_apply(
+                jax.tree_util.tree_map(pick(i), stacked_params), h, cfg,
+                kind, positions, like, moe_dispatch=moe_dispatch, rows=rows,
+            )
+            return h, (block, aux)
+
+        h, (blocks, auxes) = jax.lax.scan(
+            body_rows, h, jnp.arange(lo, hi, dtype=jnp.int32)
+        )
+        return h, write_row_blocks(caches, blocks, lo, rows), jnp.sum(auxes)
 
     def body_cache(carry, i):
         h, cache_full = carry

@@ -96,7 +96,7 @@ class ServingEngine(ServesRequests):
         self.params = self._exec.params
         self._prefill = self._exec._jit(
             lambda params, inputs, caches: M.prefill(params, inputs, cfg, caches),
-            "prefill_batch",
+            "prefill_batch", cache_arg=2,
         )
 
     @property

@@ -147,6 +147,31 @@ the entry caches with measured buckets until nothing overflows (at most K
 runs): results are always bitwise faithful, at the cost of one extra sync
 per (counted) ``overflow_retries`` iteration.
 
+The resident cache (donation and one layout)
+--------------------------------------------
+Every program that returns the cache — each decode segment variant, the
+admission prefill, ``reset_rows`` — takes it *donated*: XLA updates it in
+place and the caller's handle is consumed (``step``, ``prefill_rows`` and
+``reset_rows`` return the live cache; the one handed in must not be used
+again).  Where the compiled Pallas decode kernel reads the cache
+(``use_kernels`` on the TPU), :meth:`TierExecutor.shard_caches` also
+stores K/V with head_dim zero-padded to the TPU's 128 lanes
+(:func:`repro.models.model.pad_kv_lanes`): the TPU's default layout for
+that shape is the one the kernel and the in-place slot writes read, so no
+program re-lays out the whole cache on entry or exit (for phi3's
+head_dim of 96 the default layout otherwise puts the slots in the lanes).
+Elsewhere (the jnp path, sharded executors, interpret mode) the cache
+keeps its shape and is donated only.
+
+Since an overflow re-run can no longer read the entry caches, the entry
+segment's program first gathers what the step can write (slot
+``pos % C`` of every row in every layer, the recurrent state and the
+step counters: :func:`repro.models.model.snapshot_decode_writes`, a few
+MB) and the re-run starts from a small donated restore of those entries
+(``cache_restores`` counts them; it equals ``overflow_retries``), bitwise
+the step's entry caches.  The restore program is compiled at the first
+step of each cache shape, with the step's own programs.
+
 Defined divergence from the masked path: an exited sequence contributes
 no downstream-tier KV for that step (the masked path, which runs every
 row everywhere, does write it).  Downstream attention masks the hole via
@@ -353,8 +378,11 @@ from repro.models.model import (
     branch_logits_per_head,
     branch_logits_stacked,
     embed_decode,
+    pad_kv_lanes,
     prefill,
+    restore_decode_writes,
     run_trunk,
+    snapshot_decode_writes,
     trunk_layout,
 )
 
@@ -563,7 +591,9 @@ class TierExecutor:
     ``mesh`` / ``sharding``: execute the segment fns SPMD across a device
     mesh (see the module docstring's sharded-segment contract).  Params
     are placed at construction; callers place caches through
-    :meth:`shard_caches`.  ``sharding=None`` derives the policy via
+    :meth:`shard_caches`, which also gives them the shape whose layout
+    the programs keep (see the module docstring's resident-cache
+    section).  ``sharding=None`` derives the policy via
     :func:`~repro.sharding.policy.make_policy`.  A 1-device mesh is
     treated as unsharded.
     """
@@ -636,8 +666,13 @@ class TierExecutor:
         self._probe_offset = 0  # rotation cursor so samples cycle the batch
         self.total_layers = sum(n for _, _, n in trunk_layout(cfg))
         self._fn_cache: dict[tuple, Any] = {}
+        #: Compiled restore programs, by the shapes of (caches, snapshot).
+        self._restorers: dict[tuple, Any] = {}
         self.host_syncs = 0
         self.overflow_retries = 0
+        #: Overflow re-runs that started from the restored entry caches
+        #: (equal to ``overflow_retries``).
+        self.cache_restores = 0
         #: pipelined steps that fell back to serial (overflow retry drained
         #: the pipeline and paid its transfers inline).
         self.pipeline_fallbacks = 0
@@ -703,28 +738,41 @@ class TierExecutor:
         self._hints = {}
         self._hint_hist = {}
 
-    def segment_fn(self, index: int):
-        """The compiled full-batch callable for segment ``index``
-        (None if the segment is empty)."""
-        return self._fns[index]
+    def segment_fn(self, index: int, bucket: int | None = None):
+        """The compiled callable for segment ``index`` (None if the
+        segment is empty): full-batch, or the compacted variant that runs
+        a ``bucket``-row sub-batch."""
+        if bucket is None or self.segments[index].is_empty:
+            return self._fns[index]
+        return self._segment_fn(
+            self.segments[index], index == self._head_idx, bucket
+        )
 
     # ---------------------------------------------------------- sharding
     def shard_caches(self, caches: Any) -> Any:
-        """Place a freshly initialized cache pytree per the sharding
-        policy's cache rules (no-op when the executor has no mesh).
-        Servers call this once right after ``init_caches``; the layouts
-        then persist across decode steps through XLA's propagation."""
-        if not self.sharded:
-            return caches
-        return self.policy.shard_caches(caches)
+        """Place a freshly initialized cache pytree where the programs
+        keep it: per the sharding policy's cache rules on a mesh (the
+        layouts then persist across decode steps through XLA's
+        propagation), and, where the compiled (Mosaic) decode kernel
+        reads it, with K/V head_dim padded to the TPU's lanes
+        (:func:`repro.models.model.pad_kv_lanes`), so that no program
+        re-lays the cache out.  No-op otherwise.  Servers call this once
+        right after ``init_caches``."""
+        if self.sharded:
+            return self.policy.shard_caches(caches)
+        if self.use_kernels and kernel_ops.on_tpu():
+            return pad_kv_lanes(caches)
+        return caches
 
-    def _jit(self, fn, name: str):
+    def _jit(self, fn, name: str, *, cache_arg: int):
         """``jax.jit`` of ``fn`` as the program ``name`` (its module is
         ``jit_<name>`` in HLO dumps and profiler traces), with the
         executor's activation-sharding context active at trace time (jit
         executes the traced body once), so the model stack's ``constrain``
         call sites emit real constraints on a sharded executor and stay
-        no-ops otherwise."""
+        no-ops otherwise.  Positional argument ``cache_arg`` is the cache,
+        donated: the program writes it in place and consumes the caller's
+        handle."""
         if self.sharded:
             pol = self.policy
             body = fn
@@ -735,7 +783,7 @@ class TierExecutor:
                     return body(*args)
 
         fn.__name__ = fn.__qualname__ = name
-        return jax.jit(fn)
+        return jax.jit(fn, donate_argnums=cache_arg)
 
     def _segment_fn(
         self,
@@ -763,7 +811,11 @@ class TierExecutor:
         here since no head tier runs.  All variants share the signature
         ``fn(params, x, pos, exited, chosen, caches[, probe_rows])`` with
         full-batch x; ``pos`` is the shared () step position or the
-        continuous-batching per-sequence (B,) positions.
+        continuous-batching per-sequence (B,) positions.  ``caches`` is
+        donated and written in place (``out["caches"]``).  The entry
+        segment of a plan with a compacted downstream tier also returns
+        ``out["snap"]``, what the step can write, gathered before it
+        writes (the overflow re-run's restore point).
         """
         key = ((*seg.spec(head), probe, probe_m, degrade), bucket)
         if key in self._fn_cache:
@@ -777,6 +829,7 @@ class TierExecutor:
         use_kernels = self.use_kernels
         batched_heads = self.batched_heads
         trace_counts = self.trace_counts
+        snapshot = lo == 0 and not head and self.compaction == "bucketed"
 
         def exit_decision(logits_b, ex):
             """(take mask, entropy, exit token) for one branch head.  The
@@ -841,6 +894,15 @@ class TierExecutor:
                     # downstream KV validity is a pure function of exits, not
                     # of which rows happened to pad the bucket.
                     rows_rw = jnp.where(ex, batch, rows).astype(jnp.int32)
+            snap = None
+            if snapshot:
+                with jax.named_scope("snapshot"):
+                    snap = snapshot_decode_writes(caches, pos)
+                    # The trunk's in-place writes are ordered after the
+                    # gather, so XLA keeps no copy of the cache for it.
+                    snap, caches = jax.lax.optimization_barrier(
+                        (snap, caches)
+                    )
             with jax.named_scope("layers"):
                 h = embed_decode(params, xb, positions, cfg) if lo == 0 else xb
                 h, new_caches, _, collected = run_trunk(
@@ -959,6 +1021,8 @@ class TierExecutor:
                     else jnp.zeros((0, psub), jnp.float32)
                 )
             out: dict[str, Any] = {"caches": new_caches}
+            if snap is not None:
+                out["snap"] = snap
             logits = None
             with jax.named_scope("head"):
                 if head:
@@ -1033,7 +1097,7 @@ class TierExecutor:
             name += "_probe"
         if degrade is not None:
             name += f"_degrade{degrade}"
-        jitted = self._jit(fn, name)
+        jitted = self._jit(fn, name, cache_arg=5)
         self._fn_cache[key] = jitted
         return jitted
 
@@ -1070,6 +1134,30 @@ class TierExecutor:
             time.sleep(wait)
 
     # ------------------------------------------------- request admission
+    def prefill_fn(self, plen: int, n: int):
+        """The jitted admission prefill program for ``n`` prompts of
+        ``plen`` tokens: ``fn(params, tokens, rows, caches) -> (first
+        tokens, caches)``, ``caches`` donated (see :meth:`prefill_rows`)."""
+        key = ("prefill", plen, n)
+        fn = self._fn_cache.get(key)
+        if fn is None:
+            cfg = self.cfg
+            trace_counts = self.trace_counts
+
+            def prefill_fn(params, toks, rows_, caches_):
+                trace_counts[key] = trace_counts.get(key, 0) + 1
+                with jax.named_scope("prefill"):
+                    logits, new_caches = prefill(
+                        params, {"tokens": toks}, cfg, caches_, rows=rows_,
+                    )
+                    tok0 = jnp.argmax(logits[:, 0], -1).astype(jnp.int32)
+                return tok0, new_caches
+
+            fn = self._fn_cache[key] = self._jit(
+                prefill_fn, f"prefill_p{plen}_n{n}", cache_arg=3
+            )
+        return fn
+
     def prefill_rows(
         self, caches: Any, tokens: jax.Array, rows
     ) -> tuple[Any, jax.Array]:
@@ -1083,71 +1171,83 @@ class TierExecutor:
         an out-of-bounds sentinel (>= batch) drop their writes, letting
         callers pad admission groups to reusable (P, n) jit shapes.
 
+        ``caches`` is donated to the prefill program, which writes the rows
+        in place: the caller's handle is consumed, the returned one is the
+        live cache.
+
         Returns (new caches, first decode-step input token per prompt row
         (n,), device-resident — admission performs no host sync)."""
         with TraceAnnotation(spans.TIER_PREFILL):
             tokens = jnp.asarray(tokens, jnp.int32)
             n, plen = tokens.shape
-            key = ("prefill", plen, n)
-            fn = self._fn_cache.get(key)
-            if fn is None:
-                cfg = self.cfg
-                trace_counts = self.trace_counts
-
-                def prefill_fn(params, toks, rows_, caches_):
-                    trace_counts[key] = trace_counts.get(key, 0) + 1
-                    with jax.named_scope("prefill"):
-                        logits, new_caches = prefill(
-                            params, {"tokens": toks}, cfg, caches_,
-                            rows=rows_,
-                        )
-                        tok0 = jnp.argmax(logits[:, 0], -1).astype(jnp.int32)
-                    return tok0, new_caches
-
-                fn = self._jit(prefill_fn, f"prefill_p{plen}_n{n}")
-                self._fn_cache[key] = fn
-            tok0, caches = fn(
+            tok0, caches = self.prefill_fn(plen, n)(
                 self.params, tokens, jnp.asarray(rows, jnp.int32), caches
             )
         return caches, tok0
+
+    def reset_fn(self, n: int):
+        """The jitted ``reset_rows`` program for ``n`` rows:
+        ``fn(caches, rows) -> caches``, ``caches`` donated."""
+        key = ("reset", n)
+        fn = self._fn_cache.get(key)
+        if fn is None:
+
+            def reset_fn(caches_, rows_):
+                def walk(tree):
+                    out = {}
+                    for k, v in tree.items():
+                        if isinstance(v, dict):
+                            out[k] = walk(v)
+                        elif k == "pos":
+                            out[k] = v.at[:, rows_].set(-1, mode="drop")
+                        elif k in ("conv", "ssm"):
+                            out[k] = v.at[:, rows_].set(
+                                jnp.zeros((), v.dtype), mode="drop"
+                            )
+                        else:
+                            out[k] = v
+                    return out
+
+                return walk(caches_)
+
+            fn = self._fn_cache[key] = self._jit(
+                reset_fn, f"reset_rows_n{n}", cache_arg=0
+            )
+        return fn
 
     def reset_rows(self, caches: Any, rows) -> Any:
         """Mark cache rows empty without moving anything: per-sequence slot
         validity (``pos``) -> -1 and SSM/conv state -> 0 for the given rows
         (K/V payloads stay in place — unreachable once their slot is
         invalid).  Retirement hygiene; admission prefill also resets its
-        row implicitly, so this is optional between occupants."""
+        row implicitly, so this is optional between occupants.  ``caches``
+        is donated and consumed; the returned cache is the live one."""
         with TraceAnnotation(spans.TIER_RESET):
             rows = jnp.asarray(rows, jnp.int32)
-            n = int(rows.shape[0])
-            key = ("reset", n)
-            fn = self._fn_cache.get(key)
-            if fn is None:
-
-                def reset_fn(caches_, rows_):
-                    def walk(tree):
-                        out = {}
-                        for k, v in tree.items():
-                            if isinstance(v, dict):
-                                out[k] = walk(v)
-                            elif k == "pos":
-                                out[k] = v.at[:, rows_].set(-1, mode="drop")
-                            elif k in ("conv", "ssm"):
-                                out[k] = v.at[:, rows_].set(
-                                    jnp.zeros((), v.dtype), mode="drop"
-                                )
-                            else:
-                                out[k] = v
-                        return out
-
-                    return walk(caches_)
-
-                reset_fn.__name__ = reset_fn.__qualname__ = f"reset_rows_n{n}"
-                fn = jax.jit(reset_fn)
-                self._fn_cache[key] = fn
-            return fn(caches, rows)
+            return self.reset_fn(int(rows.shape[0]))(caches, rows)
 
     # -------------------------------------------------------------- step
+    def _restorer(self, caches: Any, snap: Any):
+        """The compiled program that puts a step's entry caches back from
+        the entry segment's snapshot (:func:`restore_decode_writes`),
+        consuming ``caches``.  Compiled ahead of time at the first step of
+        each cache shape, so an overflow re-run never compiles."""
+        key = tuple(
+            (a.shape, a.dtype)
+            for a in jax.tree_util.tree_leaves((caches, snap))
+        )
+        fn = self._restorers.get(key)
+        if fn is None:
+
+            def restore(caches_, snap_):
+                return restore_decode_writes(caches_, snap_)
+
+            fn = self._jit(
+                restore, "restore_decode_writes", cache_arg=0
+            ).lower(caches, snap).compile()
+            self._restorers[key] = fn
+        return fn
+
     def _plan_buckets(self, batch: int) -> dict[int, int]:
         """Host-side bucket plan for this step: the windowed-max survivor
         hint per downstream segment (full batch where no hint exists yet),
@@ -1262,7 +1362,8 @@ class TierExecutor:
     ) -> tuple:
         """Dispatch all tier segments and perform the single host sync.
         Returns (host dict, caches, entering-survivor counts per segment,
-        chosen, logits, alive-after-segment counts, plan-exit mask).
+        chosen, logits, alive-after-segment counts, plan-exit mask, the
+        entry segment's snapshot or None).  ``caches`` is consumed.
         ``active_np`` seeds the exit mask with the dead slots of a
         continuous-batching step (they compact away downstream exactly
         like early exits).  ``degrade=(seg_idx, layer)`` truncates the
@@ -1280,6 +1381,7 @@ class TierExecutor:
         x: jax.Array = tok
         fetch: dict[str, Any] = {}
         logits = None
+        snap = None
         last_idx = len(self.segments) if degrade is None else degrade[0] + 1
 
         for i, seg in enumerate(self.segments):
@@ -1320,6 +1422,7 @@ class TierExecutor:
                 else:
                     out = fn(self.params, x, posj, exited, chosen, caches)
             caches = out["caches"]
+            snap = out.get("snap", snap)
             exited, chosen = out["exited"], out["chosen"]
             if seg.branches:
                 fetch[f"take{i}"] = out["take"]
@@ -1366,7 +1469,7 @@ class TierExecutor:
                 if not self.segments[i].is_empty
             }
         return host, caches, entering, chosen, logits, alive_after_seg, \
-            exited_run
+            exited_run, snap
 
     def step(
         self, tok: jax.Array, pos, caches: Any, *, active=None
@@ -1378,7 +1481,12 @@ class TierExecutor:
         (B,) vector of absolute positions (continuous batching).
         ``active`` (B,) bool marks live request slots: dead slots enter the
         step pre-exited — the entry tier masks them, downstream tiers
-        compact them away, and they never count as survivors or ship."""
+        compact them away, and they never count as survivors or ship.
+
+        ``caches`` is consumed: the step's programs update it in place
+        (donated) and the returned cache is the live one.  A step that
+        dispatches nothing (every hop broken with no exit head below)
+        returns it untouched."""
         with TraceAnnotation(spans.TIER_STEP):
             return self._step(tok, pos, caches, active)
 
@@ -1499,17 +1607,19 @@ class TierExecutor:
                     probe_rows = jnp.asarray(sel, jnp.int32)
                     probe_m = m
             buckets = self._plan_buckets(batch)
-        host, new_caches, entering, chosen, logits, alive, exited_plan = \
-            self._run_once(
-                tok, pos, caches, buckets, probe_map,
-                probe_rows, probe_m, active_np, degrade,
-            )
+        (host, new_caches, entering, chosen, logits, alive, exited_plan,
+         snap) = self._run_once(
+            tok, pos, caches, buckets, probe_map,
+            probe_rows, probe_m, active_np, degrade,
+        )
+        restore = None if snap is None else self._restorer(new_caches, snap)
         used = {
             i: min(buckets.get(i, batch), batch) for i in entering
         }
         # Exit-rate spike: true survivors overflowed a planned bucket, so
         # excluded survivors carry garbage.  Re-run the whole step from the
-        # entry caches with measured buckets — correctness is never traded
+        # entry caches (restored in place from the entry segment's
+        # snapshot) with measured buckets — correctness is never traded
         # for the fast path.  One pass is NOT always enough: an excluded
         # survivor's garbage forward pass can spuriously "exit" at a later
         # segment's branch, undercounting that segment's true survivors,
@@ -1533,8 +1643,10 @@ class TierExecutor:
                         )
                         for i in entering
                     }
+                caches = restore(new_caches, snap)
+                self.cache_restores += 1
                 (host, new_caches, entering, chosen, logits, alive,
-                 exited_plan) = self._run_once(
+                 exited_plan, snap) = self._run_once(
                     tok, pos, caches, buckets, probe_map,
                     probe_rows, probe_m, active_np, degrade,
                 )

@@ -6,9 +6,17 @@ Shapes are the published widths of the served configurations (phi3-mini,
 qwen3-8b, mamba2-130m).  Nothing runs; each test asserts the compiled
 program holds the Mosaic kernel (``tpu_custom_call``), i.e. no jnp or
 interpreted fallback was compiled in its place.
+
+The served programs keep the KV cache resident: at phi3-mini widths with
+the kernels on, no program that writes the cache (the edge segment, the
+cloud segment at buckets 4 and 8, an admission prefill, ``reset_rows``,
+the overflow re-run's restore) copies it — neither a whole stacked leaf
+nor a per-layer slice, in any layout — and every cache input is donated
+into an output (``input_output_alias``), so each updates it in place.
 """
 
 import dataclasses
+import re
 
 import jax
 import jax.numpy as jnp
@@ -24,6 +32,8 @@ from repro.kernels.entropy_exit import (
 )
 from repro.kernels.flash_decode import flash_decode_pallas
 from repro.kernels.ssd_scan import ssd_scan_pallas, ssd_update_pallas
+from repro.models import model as M
+from repro.serving.partitioned import PartitionedServer
 
 BF16, F32, I32 = jnp.bfloat16, jnp.float32, jnp.int32
 
@@ -177,3 +187,96 @@ def test_phi3_tier_segment_compiles(one_chip, no_persistent_cache,
         params, tok, pos, exited, pos, caches
     ).compile()
     assert compiled.as_text().count("tpu_custom_call") >= 2
+
+
+SLOTS, CONTEXT, LAYERS = 8, 512, 4
+
+
+def cache_copies(text: str) -> list[str]:
+    """Result shapes of the ``copy`` instructions that move a whole cache
+    leaf, K/V ``[L, B, C, K, D]`` or slot positions ``[L, B, C]``, or one
+    layer of one."""
+    leaf = re.compile(rf"^(\d+,)?{SLOTS},{CONTEXT}(,\d+,\d+)?$")
+    shapes = re.findall(r"= \w+\[([\d,]*)\]\S* copy\(", text)
+    return [s for s in shapes if leaf.match(s)]
+
+
+def cache_params(text: str) -> tuple[list[str], list[str]]:
+    """Entry parameters holding a cache leaf, and those of them that no
+    output aliases."""
+    header = text.split("\n", 1)[0]
+    aliased = {
+        int(p) for p in
+        re.findall(r"\(\s*(\d+),\s*\{[^}]*\},\s*[\w-]+-alias\)", header)
+    }
+    entry = re.search(r"^ENTRY [^(]*\((.*)\) -> ", text, re.M).group(1)
+    names = re.findall(r"([\w.]+): [a-z]\w*\[", entry)
+    cache = [(i, n) for i, n in enumerate(names) if n.startswith("caches")]
+    return [n for _, n in cache], [n for i, n in cache if i not in aliased]
+
+
+@pytest.fixture(scope="module")
+def programs(one_chip, no_persistent_cache):
+    """Compiled HLO text of each program that writes the cache."""
+    mp = pytest.MonkeyPatch()
+    mp.setattr(ops, "on_tpu", lambda: True)
+    try:
+        cfg = dataclasses.replace(
+            get_config("phi3_mini_3_8b"), num_layers=LAYERS,
+            branch_layers=(1,), param_dtype="bfloat16",
+        )
+
+        def placed(tree):
+            return jax.tree_util.tree_map(
+                lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                               sharding=one_chip),
+                tree,
+            )
+
+        def sds(shape, dtype):
+            return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+        params = placed(jax.eval_shape(
+            lambda k: M.init_params(k, cfg), jax.random.PRNGKey(0)
+        ))
+        server = PartitionedServer(cfg, params, 2, slots=SLOTS,
+                                   context_len=CONTEXT)
+        ex = server.executor
+        assert ex.use_kernels
+        caches = placed(jax.eval_shape(
+            lambda: ex.shard_caches(M.init_caches(cfg, SLOTS, CONTEXT))
+        ))
+        assert caches["blocks"]["self"]["k"].shape[-1] == 128  # 96, padded
+        tok, pos = sds((SLOTS, 1), I32), sds((SLOTS,), I32)
+        exited = sds((SLOTS,), jnp.bool_)
+        hidden = sds((SLOTS, 1, cfg.d_model), BF16)
+        edge = ex.segment_fn(0)
+        out = {"edge": edge.lower(params, tok, pos, exited, pos, caches)}
+        for b in (4, 8):
+            out[f"cloud_b{b}"] = ex.segment_fn(1, b).lower(
+                params, hidden, pos, exited, pos, caches
+            )
+        out["prefill"] = ex.prefill_fn(256, 1).lower(
+            params, sds((1, 256), I32), sds((1,), I32), caches
+        )
+        out["reset"] = ex.reset_fn(1).lower(caches, sds((1,), I32))
+        texts = {k: v.compile().as_text() for k, v in out.items()}
+        snap = placed(jax.eval_shape(
+            edge, params, tok, pos, exited, pos, caches
+        )["snap"])
+        texts["restore"] = ex._restorer(caches, snap).as_text()
+        return texts
+    finally:
+        mp.undo()
+
+
+@pytest.mark.parametrize(
+    "name", ["edge", "cloud_b4", "cloud_b8", "prefill", "reset", "restore"]
+)
+def test_no_cache_copy_and_cache_donated(programs, name):
+    text = programs[name]
+    assert cache_copies(text) == []
+    params, unaliased = cache_params(text)
+    assert len(params) >= 3 and unaliased == []  # K, V, slots at least
+    if name in ("edge", "cloud_b4", "cloud_b8"):
+        assert "tpu_custom_call" in text  # flash_decode compiled in
