@@ -197,7 +197,7 @@ def step_logits(server, prompts: np.ndarray, context: int, tok=None):
     (tok, main-head logits (B, V) float32, {branch layer: entropy (B,)})."""
     ex = server.executor
     b, plen = prompts.shape
-    caches = ex.shard_caches(M.init_caches(server.cfg, b, context))
+    caches = ex.new_caches(b, context)
     caches, tok0 = ex.prefill_rows(caches, prompts, np.arange(b))
     if tok is None:
         tok = np.asarray(tok0)[:, None]

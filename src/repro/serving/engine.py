@@ -117,9 +117,7 @@ class ServingEngine(ServesRequests):
         prompt_len = inputs["tokens"].shape[1]
         if self.cfg.frontend == "vision":
             prompt_len += self.cfg.num_patches
-        caches = self._exec.shard_caches(
-            M.init_caches(self.cfg, batch, self.context_len)
-        )
+        caches = self._exec.new_caches(batch, self.context_len)
         logits, caches = self._prefill(self.params, inputs, caches)
         return {
             "caches": caches,

@@ -184,7 +184,6 @@ class RequestScheduler:
                 "patch embeds / audio encoder states are per-batch, not "
                 "per-slot)"
             )
-        from repro.models import model as M  # serving <-> models layering
 
         self.server = server
         self.executor = server.executor
@@ -203,12 +202,10 @@ class RequestScheduler:
         self.requeue_on_fail = requeue_on_fail
         self.max_requeues = max_requeues
 
-        # Mesh-sharded executors place the slot caches under the policy's
-        # cache rules up front (no-op otherwise); admission prefill and
+        # Mesh-sharded executors create the slot caches in place under the
+        # policy's cache rules, each device its shard; admission prefill and
         # decode steps then keep the layouts through propagation.
-        self.caches = self.executor.shard_caches(
-            M.init_caches(cfg, slots, context_len)
-        )
+        self.caches = self.executor.new_caches(slots, context_len)
         self.pos = np.zeros(slots, np.int32)  # next decode position per slot
         self.active = np.zeros(slots, bool)
         self.tok_dev = jnp.zeros((slots, 1), jnp.int32)

@@ -154,7 +154,7 @@ admission prefill, ``reset_rows`` — takes it *donated*: XLA updates it in
 place and the caller's handle is consumed (``step``, ``prefill_rows`` and
 ``reset_rows`` return the live cache; the one handed in must not be used
 again).  Where the compiled Pallas decode kernel reads the cache
-(``use_kernels`` on the TPU), :meth:`TierExecutor.shard_caches` also
+(``use_kernels`` on the TPU), :meth:`TierExecutor.new_caches` also
 stores K/V with head_dim zero-padded to the TPU's 128 lanes
 (:func:`repro.models.model.pad_kv_lanes`): the TPU's default layout for
 that shape is the one the kernel and the in-place slot writes read, so no
@@ -285,9 +285,9 @@ turns every segment function into an SPMD program:
     per-architecture ``param_spec`` rules (attention heads / FFN hidden /
     MoE expert dim / vocab on the ``model`` axis, FSDP over ``data`` where
     configured, indivisible dims cleanly replicated);
-  * **KV/SSM caches** are placed by :meth:`TierExecutor.shard_caches`
-    (servers call it right after ``init_caches``) under ``cache_spec`` —
-    kv-heads on ``model`` when divisible, else head_dim; sharded layouts
+  * **KV/SSM caches** are created by :meth:`TierExecutor.new_caches`
+    under ``cache_spec`` — kv-heads on ``model`` when divisible, else
+    head_dim — each device making only its own shard; sharded layouts
     then persist across decode steps through XLA's propagation;
   * **activations** inside every segment fn are constrained through the
     :mod:`repro.sharding.ctx` context, which the model stack's existing
@@ -378,6 +378,7 @@ from repro.models.model import (
     branch_logits_per_head,
     branch_logits_stacked,
     embed_decode,
+    init_caches,
     pad_kv_lanes,
     prefill,
     restore_decode_writes,
@@ -590,8 +591,8 @@ class TierExecutor:
 
     ``mesh`` / ``sharding``: execute the segment fns SPMD across a device
     mesh (see the module docstring's sharded-segment contract).  Params
-    are placed at construction; callers place caches through
-    :meth:`shard_caches`, which also gives them the shape whose layout
+    are placed at construction; callers get caches from
+    :meth:`new_caches`, which also gives them the shape whose layout
     the programs keep (see the module docstring's resident-cache
     section).  ``sharding=None`` derives the policy via
     :func:`~repro.sharding.policy.make_policy`.  A 1-device mesh is
@@ -668,6 +669,8 @@ class TierExecutor:
         self._fn_cache: dict[tuple, Any] = {}
         #: Compiled restore programs, by the shapes of (caches, snapshot).
         self._restorers: dict[tuple, Any] = {}
+        #: Sharded cache creators, by (batch, context_len).
+        self._cache_makers: dict[tuple, Any] = {}
         self.host_syncs = 0
         self.overflow_retries = 0
         #: Overflow re-runs that started from the restored entry caches
@@ -756,13 +759,44 @@ class TierExecutor:
         propagation), and, where the compiled (Mosaic) decode kernel
         reads it, with K/V head_dim padded to the TPU's lanes
         (:func:`repro.models.model.pad_kv_lanes`), so that no program
-        re-lays the cache out.  No-op otherwise.  Servers call this once
-        right after ``init_caches``."""
+        re-lays the cache out.  No-op otherwise.  Servers create their
+        caches with :meth:`new_caches`; on a mesh this route, which
+        first holds the whole cache where it was made, only places
+        caches that tests build themselves."""
         if self.sharded:
             return self.policy.shard_caches(caches)
         if self.use_kernels and kernel_ops.on_tpu():
             return pad_kv_lanes(caches)
         return caches
+
+    def new_caches(self, batch: int, context_len: int) -> Any:
+        """Fresh caches for ``batch`` rows of ``context_len`` positions,
+        placed as :meth:`shard_caches` places them (the servers' one way
+        to get caches).  On a mesh each device creates only its own shard
+        (:meth:`cache_maker`)."""
+        if self.sharded:
+            return self.cache_maker(batch, context_len)()
+        return self.shard_caches(init_caches(self.cfg, batch, context_len))
+
+    def cache_maker(self, batch: int, context_len: int):
+        """The jitted program that creates a sharded executor's fresh
+        caches straight into the policy's cache shardings (those that
+        :meth:`shard_caches` places by), so the whole cache never sits on
+        one device: at qwen3-8b's widths 32 rows x 2048 positions are
+        9.7 GB, of which each of four chips keeps a 2.4 GB shard."""
+        key = (batch, context_len)
+        fn = self._cache_makers.get(key)
+        if fn is None:
+
+            def make():
+                return init_caches(self.cfg, batch, context_len)
+
+            make.__name__ = make.__qualname__ = f"init_caches_n{batch}"
+            shardings = self.policy.cache_shardings(jax.eval_shape(make))
+            fn = self._cache_makers[key] = jax.jit(
+                make, out_shardings=shardings
+            )
+        return fn
 
     def _jit(self, fn, name: str, *, cache_arg: int):
         """``jax.jit`` of ``fn`` as the program ``name`` (its module is

@@ -13,9 +13,15 @@ cloud segment at buckets 4 and 8, an admission prefill, ``reset_rows``,
 the overflow re-run's restore) copies it — neither a whole stacked leaf
 nor a per-layer slice, in any layout — and every cache input is donated
 into an output (``input_output_alias``), so each updates it in place.
+
+Served tensor-parallel on a described four-chip host at qwen3-8b's
+published widths, the same programs keep the sharded cache in place too,
+move no part of it between chips, all-reduce twice a layer, and fit a
+chip's memory with the longest admission.
 """
 
 import dataclasses
+import math
 import re
 
 import jax
@@ -201,17 +207,21 @@ def cache_copies(text: str) -> list[str]:
     return [s for s in shapes if leaf.match(s)]
 
 
-def cache_params(text: str) -> tuple[list[str], list[str]]:
+def cache_params(text: str, is_cache=None) -> tuple[list[str], list[str]]:
     """Entry parameters holding a cache leaf, and those of them that no
-    output aliases."""
+    output aliases.  A cache leaf is a parameter named ``caches...``, or
+    one that ``is_cache(name, shape)`` picks (a partitioned program's
+    parameters lose their names)."""
     header = text.split("\n", 1)[0]
     aliased = {
         int(p) for p in
         re.findall(r"\(\s*(\d+),\s*\{[^}]*\},\s*[\w-]+-alias\)", header)
     }
     entry = re.search(r"^ENTRY [^(]*\((.*)\) -> ", text, re.M).group(1)
-    names = re.findall(r"([\w.]+): [a-z]\w*\[", entry)
-    cache = [(i, n) for i, n in enumerate(names) if n.startswith("caches")]
+    params = re.findall(r"([\w.]+): [a-z]\w*\[([\d,]*)\]", entry)
+    is_cache = is_cache or (lambda name, shape: name.startswith("caches"))
+    cache = [(i, n) for i, (n, shape) in enumerate(params)
+             if is_cache(n, shape)]
     return [n for _, n in cache], [n for i, n in cache if i not in aliased]
 
 
@@ -280,3 +290,151 @@ def test_no_cache_copy_and_cache_donated(programs, name):
     assert len(params) >= 3 and unaliased == []  # K, V, slots at least
     if name in ("edge", "cloud_b4", "cloud_b8"):
         assert "tpu_custom_call" in text  # flash_decode compiled in
+
+
+# ---------------------------------------------------------------- four chips
+#: qwen3-8b served tensor-parallel on a (1, 4) mesh of the described v5e
+#: host: 32 slots of 2048 positions, K=2 cut at layer 18, the longest
+#: admission 32 prompts of 1536 tokens.
+QWEN_SLOTS, QWEN_CONTEXT, QWEN_PROMPT, QWEN_CUT = 32, 2048, 1536, 18
+
+
+def hlo_computations(text: str) -> dict[str, str]:
+    """Each computation's body text by name (ENTRY under "ENTRY")."""
+    out = {}
+    for m in re.finditer(r"^(ENTRY )?%?([\w.\-]+) \([^\n]*\{\n(.*?)^\}",
+                         text, re.M | re.S):
+        out["ENTRY" if m.group(1) else m.group(2)] = m.group(3)
+    return out
+
+
+def loop_ops(text: str, opcode: str) -> int:
+    """``opcode`` instructions executed by the while loops reachable from
+    the entry computation: each loop body's count times its trip count
+    (the s32 constant of its condition), nested loops included."""
+    comps = hlo_computations(text)
+    op = re.compile(rf" {re.escape(opcode)}(-start)?\(")
+
+    def executed(name: str, in_loop: bool) -> int:
+        body = comps[name]
+        n = len(op.findall(body)) if in_loop else 0
+        for cond, sub in re.findall(
+                r" while\(.*condition=%([\w.\-]+), body=%([\w.\-]+)", body):
+            trips = re.findall(r"s32\[\]\S* constant\((\d+)\)", comps[cond])
+            assert len(trips) == 1, comps[cond][:400]
+            n += int(trips[0]) * executed(sub, True)
+        return n
+
+    return executed("ENTRY", False)
+
+
+def moved_shapes(text: str) -> list[list[int]]:
+    """Result shapes of the all-gathers and all-to-alls."""
+    shapes = re.findall(
+        r"= \w+\[([\d,]*)\]\S* (?:all-gather|all-to-all)(?:-start)?\(", text)
+    return [[int(d) for d in s.split(",") if d] for s in shapes]
+
+
+@pytest.fixture(scope="module")
+def mesh_programs(topo, no_persistent_cache):
+    """Compiled programs of the qwen3-8b K=2 executor on the described
+    (1, 4) mesh at published widths: ``(compiled, HLO text)`` by name."""
+    import numpy as np
+    from jax.sharding import AxisType, Mesh
+
+    from repro.sharding.policy import ShardingPolicy, make_policy
+
+    mp = pytest.MonkeyPatch()
+    # A described device holds no arrays: the executor keeps the params as
+    # the placed shapes it is handed.
+    mp.setattr(ShardingPolicy, "shard_params", lambda self, params: params)
+    try:
+        mesh = Mesh(np.array(topo.devices[:4]).reshape(1, 4),
+                    ("data", "model"), axis_types=(AxisType.Auto,) * 2)
+        cfg = dataclasses.replace(get_config("qwen3_8b"),
+                                  param_dtype="bfloat16")
+        pol = make_policy(mesh, cfg)
+
+        def placed(shapes, shardings):
+            return jax.tree_util.tree_map(
+                lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                                  sharding=s),
+                shapes, shardings)
+
+        shapes = jax.eval_shape(lambda k: M.init_params(k, cfg),
+                                jax.random.PRNGKey(0))
+        params = placed(shapes, pol.params_shardings(shapes))
+        server = PartitionedServer(cfg, params, QWEN_CUT, slots=QWEN_SLOTS,
+                                   context_len=QWEN_CONTEXT, mesh=mesh)
+        ex = server.executor
+        assert ex.sharded and not ex.use_kernels
+        shapes = jax.eval_shape(
+            lambda: M.init_caches(cfg, QWEN_SLOTS, QWEN_CONTEXT))
+        caches = placed(shapes, pol.cache_shardings(shapes))
+        rep = pol.replicated()
+
+        def sds(shape, dtype):
+            return jax.ShapeDtypeStruct(shape, dtype, sharding=rep)
+
+        tok, pos = sds((QWEN_SLOTS, 1), I32), sds((QWEN_SLOTS,), I32)
+        exited = sds((QWEN_SLOTS,), jnp.bool_)
+        hidden = sds((QWEN_SLOTS, 1, cfg.d_model), BF16)
+        lowered = {
+            "edge": ex.segment_fn(0).lower(params, tok, pos, exited, pos,
+                                           caches),
+            "cloud_b32": ex.segment_fn(1, QWEN_SLOTS).lower(
+                params, hidden, pos, exited, pos, caches),
+            "prefill": ex.prefill_fn(QWEN_PROMPT, QWEN_SLOTS).lower(
+                params, sds((QWEN_SLOTS, QWEN_PROMPT), I32),
+                sds((QWEN_SLOTS,), I32), caches),
+            "reset": ex.reset_fn(QWEN_SLOTS).lower(
+                caches, sds((QWEN_SLOTS,), I32)),
+            "new_caches": ex.cache_maker(QWEN_SLOTS, QWEN_CONTEXT).lower(),
+        }
+        out = {}
+        for name, low in lowered.items():
+            compiled = low.compile()
+            out[name] = (compiled, compiled.as_text())
+        return out
+    finally:
+        mp.undo()
+
+
+MESH_WRITERS = ["edge", "cloud_b32", "prefill", "reset"]
+
+
+@pytest.mark.parametrize("name", MESH_WRITERS)
+def test_mesh_program_updates_the_cache_in_place(mesh_programs, name):
+    """Every cache input is donated into an output, and no all-gather or
+    all-to-all moves a cache leaf or a slice of one (a K/V or slot row
+    has the context's 2048 positions, a chunk of it 1024)."""
+    _, text = mesh_programs[name]
+    # A per-chip shape with the slots and the context: K, V, slot positions.
+    leaf = f"{QWEN_SLOTS},{QWEN_CONTEXT}"
+    params, unaliased = cache_params(text, lambda _, shape: leaf in shape)
+    assert len(params) == 3 and unaliased == [], params
+    for shape in moved_shapes(text):
+        assert QWEN_CONTEXT not in shape and QWEN_CONTEXT // 2 not in shape, \
+            shape
+        assert math.prod(shape) * 2 <= 1 << 20, shape
+
+
+@pytest.mark.parametrize("name", ["edge", "cloud_b32"])
+def test_mesh_decode_all_reduces_twice_a_layer(mesh_programs, name):
+    """Tensor parallelism costs each layer two all-reduces: the attention
+    output and the MLP's down projection (each tier holds 18 layers)."""
+    _, text = mesh_programs[name]
+    assert loop_ops(text, "all-reduce") == 2 * QWEN_CUT
+
+
+@pytest.mark.parametrize("name", MESH_WRITERS + ["new_caches"])
+def test_mesh_program_fits_a_chip(mesh_programs, name):
+    """Arguments plus temporaries per chip stay under 15 GB of a v5e's 16
+    (the admission prefill is the largest: 6.5 GB of weights and cache,
+    about 6.2 GB of temporaries)."""
+    compiled, _ = mesh_programs[name]
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15e9
+    if name == "new_caches":
+        # Each chip creates its own quarter of the 9.7 GB cache.
+        assert 2.3e9 < mem.output_size_in_bytes < 2.6e9
