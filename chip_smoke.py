@@ -359,8 +359,12 @@ def one_chip(cfg, seed: int, hbm_bytes: int) -> None:
     server = PartitionedServer(cfg, params, SPLIT, slots=SLOTS,
                                context_len=context)
     check(server.executor.use_kernels, "use_kernels=None resolved to jnp")
+    # Later servers take the served tree: its leaves are shared, not
+    # transposed again, and the canonical q/k/v stacks are freed.
+    params = server.params
     calls = kernel_calls(server)
-    log(f"[kernels] tpu_custom_call per lowered segment: {calls}")
+    log(f"[kernels] tpu_custom_call per lowered segment: {calls}; served "
+        f"transposed: {server.executor.served_transposed}")
     check(all(c >= 1 for c in calls) and calls[0] >= 2,
           "a tier segment lowered without its Pallas kernels")
 

@@ -371,6 +371,16 @@ def attn_init(key, cfg: ModelConfig) -> Params:
     return p
 
 
+def _project(params: Params, name: str, x: jax.Array, dtype) -> jax.Array:
+    """``x`` through projection ``name``: read from its ``[out, in]``
+    transpose ``name_t`` where the served tree holds one
+    (:func:`repro.models.model.served_params`), else ``dense``."""
+    w_t = params.get(name + "_t")
+    if w_t is None:
+        return dense(params[name], x, dtype)
+    return jnp.einsum("...i,oi->...o", x.astype(dtype), w_t.astype(dtype))
+
+
 def attn_apply(
     params: Params,
     x: jax.Array,  # (B, S, d_model)
@@ -410,10 +420,10 @@ def attn_apply(
     window = cfg.sliding_window if window is None else window
     dtype = x.dtype
 
-    q = dense(params["wq"], x, dtype).reshape(b, s, kh * g, hd)
+    q = _project(params, "wq", x, dtype).reshape(b, s, kh * g, hd)
     if kv_override is None:
-        k = dense(params["wk"], x, dtype).reshape(b, s, kh, hd)
-        v = dense(params["wv"], x, dtype).reshape(b, s, kh, hd)
+        k = _project(params, "wk", x, dtype).reshape(b, s, kh, hd)
+        v = _project(params, "wv", x, dtype).reshape(b, s, kh, hd)
     else:
         k, v = kv_override
 

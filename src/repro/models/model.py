@@ -60,6 +60,7 @@ __all__ = [
     "init_params",
     "init_caches",
     "pad_kv_lanes",
+    "served_params",
     "snapshot_decode_writes",
     "restore_decode_writes",
     "run_trunk",
@@ -213,6 +214,46 @@ def pad_kv_lanes(caches: Params) -> Params:
         return {k: walk(v) for k, v in tree.items()}
 
     return walk(caches)
+
+
+#: Attention projections that :func:`served_params` stores transposed.
+TRANSPOSED_PROJECTIONS = ("wq", "wk", "wv")
+
+
+def _swap_io(w):
+    """``w`` with its last two axes swapped (an abstract leaf, as a compile
+    for a described chip hands over, keeps its sharding)."""
+    if isinstance(w, jax.ShapeDtypeStruct):
+        return jax.ShapeDtypeStruct((*w.shape[:-2], w.shape[-1], w.shape[-2]),
+                                    w.dtype, sharding=w.sharding)
+    return jnp.swapaxes(w, -1, -2)
+
+
+def served_params(cfg: ModelConfig, params: Params) -> Params:
+    """The tree a server computes from: where ``cfg.head_dim`` is not a
+    multiple of :data:`LANES`, each trunk stack's ``attn/wq``, ``wk`` and
+    ``wv`` (``[L, in, out]``) become ``wq_t``, ``wk_t`` and ``wv_t``
+    (``[L, out, in]``); otherwise ``params`` itself.
+
+    With a head width off the lanes (phi3: 96) XLA computes the
+    projections from the weight transposed rather than re-lay out the
+    activation for the head reshape, and hoists that transpose of the
+    whole stack to the program's entry: every decode step would copy the
+    three stacks.  Stored transposed, their default layout is the one the
+    dots read.  The new names keep a canonical tree from being read as a
+    served one.  Every dict of the result is new (the leaves not
+    transposed are shared); a served tree comes back with its leaves."""
+    if cfg.head_dim % LANES == 0:
+        return params
+    out = jax.tree_util.tree_map(lambda a: a, params)
+    for stack, kind, _ in trunk_layout(cfg):
+        if kind.mixer != "gqa":
+            continue
+        attn = out[stack]["attn"]
+        for name in TRANSPOSED_PROJECTIONS:
+            if name in attn:
+                attn[name + "_t"] = _swap_io(attn.pop(name))
+    return out
 
 
 #: Leaves of a slotted cache (one that carries per-slot ``pos``) laid out

@@ -163,6 +163,13 @@ head_dim of 96 the default layout otherwise puts the slots in the lanes).
 Elsewhere (the jnp path, sharded executors, interpret mode) the cache
 keeps its shape and is donated only.
 
+The weights follow the same rule: where the head width is not a multiple
+of the lanes, ``TierExecutor.params`` holds the served tree of
+:func:`repro.models.model.served_params` (the q/k/v projections stored
+``[L, out, in]`` as ``wq_t`` / ``wk_t`` / ``wv_t``), so no program copies
+the three weight stacks into the layout its dots read.  Every assignment
+to ``params`` derives it (``served_transposed`` names the leaves).
+
 Since an overflow re-run can no longer read the entry caches, the entry
 segment's program first gathers what the step can write (slot
 ``pos % C`` of every row in every layer, the recurrent state and the
@@ -383,6 +390,7 @@ from repro.models.model import (
     prefill,
     restore_decode_writes,
     run_trunk,
+    served_params,
     snapshot_decode_writes,
     trunk_layout,
 )
@@ -633,7 +641,8 @@ class TierExecutor:
             self.policy = (
                 sharding if sharding is not None else make_policy(mesh, cfg)
             )
-            params = self.policy.shard_params(params)
+        #: Paths of the weight leaves served transposed (``params``).
+        self.served_transposed: tuple[str, ...] = ()
         self.params = params
         self.compaction = compaction
         self.simulate_network = simulate_network
@@ -708,6 +717,27 @@ class TierExecutor:
         self.failed_steps = 0
         self.fault_retries = 0
         self.install(segments)
+
+    @property
+    def params(self) -> Any:
+        """The served weight tree (module docstring, resident cache)."""
+        return self._params
+
+    @params.setter
+    def params(self, params: Any) -> None:
+        """Place ``params`` (a canonical or a served tree; ``None`` frees
+        them) under the policy on a mesh, then derive the served tree."""
+        if params is not None:
+            if self.sharded:
+                params = self.policy.shard_params(params)
+            params = served_params(self.cfg, params)
+            self.served_transposed = tuple(
+                f"{stack}/attn/{leaf}"
+                for stack, _, _ in trunk_layout(self.cfg)
+                for leaf in params[stack].get("attn", ())
+                if leaf.endswith("_t")
+            )
+        self._params = params
 
     # -------------------------------------------------------------- plan
     def install(self, segments: Sequence[TierSegment]) -> None:

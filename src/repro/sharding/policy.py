@@ -85,6 +85,12 @@ class ShardingPolicy:
             return spec(
                 fsdp and self._maybe(fsdp, din), self._maybe(tp, dout)
             )
+        if re.search(r"(attn|xattn)/(wq|wk|wv)_t$", path):
+            # Served transposed, [out, in] (models.model.served_params).
+            dout, din = shape[-2], shape[-1]
+            return spec(
+                self._maybe(tp, dout), fsdp and self._maybe(fsdp, din)
+            )
         if re.search(r"(attn|xattn)/wo$", path):
             din, dout = shape[-2], shape[-1]
             return spec(self._maybe(tp, din), fsdp and self._maybe(fsdp, dout))

@@ -179,6 +179,31 @@ class TestShardedEquivalence:
         )
         assert srv.executor.use_kernels is False
 
+    def test_served_projections_shard_their_out_axis(self):
+        """A head width off the lanes on a (1, 4) mesh: the served
+        ``wq_t`` (``[L, out, in]``) takes the policy's rule, its out axis
+        over "model", and the sharded trajectory matches one device's."""
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
+        cfg = dataclasses.replace(
+            get_smoke_config("phi3_mini_3_8b"), num_layers=4,
+            branch_layers=(1, 3), head_dim=24, dtype="float32",
+        )
+        params = M.init_params(jax.random.PRNGKey(0), cfg)
+        mesh = make_local_mesh(data=1, model=4)
+        srv = PartitionedServer(cfg, params, 2, mesh=mesh)
+        ex = srv.executor
+        assert ex.served_transposed == (
+            "blocks/attn/wq_t", "blocks/attn/wk_t", "blocks/attn/wv_t")
+        for name in ex.served_transposed:
+            leaf = ex.params["blocks"]["attn"][name.split("/")[-1]]
+            spec = ex.policy.param_spec(name, leaf.shape)
+            assert spec == P(None, "model", None)
+            assert leaf.sharding.is_equivalent_to(
+                NamedSharding(mesh, spec), leaf.ndim)
+        ref = _trajectory(PartitionedServer(cfg, params, 2), cfg)
+        _assert_same_trajectory(ref, _trajectory(srv, cfg))
+
     def test_sharded_params_actually_span_devices(self, gqa_model):
         """The policy must place at least one trunk tensor across >1
         device — otherwise the "sharded" run is silently replicated."""

@@ -13,6 +13,8 @@ cloud segment at buckets 4 and 8, an admission prefill, ``reset_rows``,
 the overflow re-run's restore) copies it — neither a whole stacked leaf
 nor a per-layer slice, in any layout — and every cache input is donated
 into an output (``input_output_alias``), so each updates it in place.
+At phi3-mini's full 32 layers the edge segment and an admission read the
+executor's served weights without copying any of them.
 
 Served tensor-parallel on a described four-chip host at qwen3-8b's
 published widths, the same programs keep the sharded cache in place too,
@@ -290,6 +292,63 @@ def test_no_cache_copy_and_cache_donated(programs, name):
     assert len(params) >= 3 and unaliased == []  # K, V, slots at least
     if name in ("edge", "cloud_b4", "cloud_b8"):
         assert "tpu_custom_call" in text  # flash_decode compiled in
+
+
+#: phi3-mini at its full depth, cut where its cell cuts: at ``LAYERS`` the
+#: scan is too short for XLA to hoist a copy of a whole weight stack.
+FULL_LAYERS, FULL_CUT = 32, 16
+
+
+def weight_copies(text: str, width: int) -> list[str]:
+    """The ``copy`` instructions that read an entry parameter named
+    ``params...`` or make a ``[..., width, width]`` result (a q/k/v weight,
+    whole stack or one layer)."""
+    square = re.compile(rf"^(\d+,)?{width},{width}$")
+    return [
+        m.group(0) for m in re.finditer(
+            r"[\w.\-]+ = \w+\[([\d,]*)\]\S* copy\(%?([\w.\-]+)\)", text)
+        if m.group(2).startswith("params") or square.match(m.group(1))
+    ]
+
+
+def test_phi3_full_depth_reads_weights_in_place(one_chip, no_persistent_cache,
+                                                monkeypatch):
+    """The 32-layer edge segment and a 256-token admission, compiled from
+    the executor's served tree: with phi3's head width of 96 off the lanes
+    the canonical q/k/v stacks were copied whole into the layout the dots
+    read on every step (``bf16[32, 3072, 3072]``, three of them in the
+    edge); served transposed, no program copies a weight."""
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)
+    cfg = dataclasses.replace(
+        get_config("phi3_mini_3_8b"), num_layers=FULL_LAYERS,
+        branch_layers=(8, 16, 24), param_dtype="bfloat16",
+    )
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def placed(tree):
+        return jax.tree_util.tree_map(lambda a: sds(a.shape, a.dtype), tree)
+
+    params = placed(jax.eval_shape(
+        lambda k: M.init_params(k, cfg), jax.random.PRNGKey(0)
+    ))
+    ex = PartitionedServer(cfg, params, FULL_CUT, slots=SLOTS,
+                           context_len=CONTEXT).executor
+    caches = placed(jax.eval_shape(
+        lambda: ex.shard_caches(M.init_caches(cfg, SLOTS, CONTEXT))
+    ))
+    tok, pos = sds((SLOTS, 1), I32), sds((SLOTS,), I32)
+    exited = sds((SLOTS,), jnp.bool_)
+    edge = ex.segment_fn(0).lower(
+        ex.params, tok, pos, exited, pos, caches
+    ).compile().as_text()
+    prefill = ex.prefill_fn(256, 1).lower(
+        ex.params, sds((1, 256), I32), sds((1,), I32), caches
+    ).compile().as_text()
+    assert weight_copies(edge, cfg.d_model) == []
+    assert weight_copies(prefill, cfg.d_model) == []
+    assert "tpu_custom_call" in edge  # flash_decode compiled in
 
 
 # ---------------------------------------------------------------- four chips
