@@ -1,6 +1,6 @@
 PY := python
 
-.PHONY: test test-fast test-sharded bench-serving bench-serving-fast bench-overlap bench-requests bench-faults bench-kernels bench-kernels-full bench-check example
+.PHONY: test test-fast test-sharded bench-kernels bench-kernels-full example
 
 # Tier-1 verify (ROADMAP): the full suite with the src layout on the path.
 test:
@@ -16,31 +16,6 @@ test-fast: test-sharded
 test-sharded:
 	XLA_FLAGS="--xla_force_host_platform_device_count=8" PYTHONPATH=src $(PY) -m pytest -x -q tests/test_sharded_tiers.py
 
-bench-serving:
-	PYTHONPATH=src $(PY) benchmarks/serving_step.py
-
-# CI smoke: one batch/split/regime cell, short step counts.
-bench-serving-fast:
-	REPRO_BENCH_FAST=1 PYTHONPATH=src $(PY) benchmarks/serving_step.py
-
-# Serial-vs-pipelined overlap cell only: asserts pipelined steady-state
-# step time <= serial under simulate_network=True and the plan flip.
-bench-overlap:
-	REPRO_BENCH_FAST=1 REPRO_BENCH_ONLY=overlap PYTHONPATH=src $(PY) benchmarks/serving_step.py
-
-# Continuous-vs-lock-step request cell only: Poisson arrivals, mixed
-# prompt lengths/budgets with early exits; asserts continuous admission
-# beats gang (lock-step) tokens/sec at one host sync per decode step.
-bench-requests:
-	REPRO_BENCH_FAST=1 REPRO_BENCH_ONLY=requests PYTHONPATH=src $(PY) benchmarks/serving_step.py
-
-# Fault-plane cell only: scripted mid-run link flap on a K=3 stack ->
-# retries, breaker open, degraded tokens from the fallback head, and an
-# availability re-solve that moves the cut off the sick hop.  Asserts
-# every request completes with no leaked KV slots.
-bench-faults:
-	REPRO_BENCH_FAST=1 REPRO_BENCH_ONLY=faults PYTHONPATH=src $(PY) benchmarks/serving_step.py
-
 # Kernel-vs-jnp decode hot path sweep (flash_decode / fused exit decision /
 # ssd_update / end-to-end TierExecutor step) in CI smoke mode: tiny shapes,
 # kernels in interpret mode off-TPU, trajectory + 1-sync asserts inline.
@@ -50,11 +25,6 @@ bench-kernels:
 # Full sweep incl. the serving-scale jnp reference timings.
 bench-kernels-full:
 	PYTHONPATH=src $(PY) benchmarks/kernel_micro.py
-
-# Diff the emitted BENCH_*.json bundles against the last committed ones:
-# strict (structural) metrics exactly, wall-clock within REPRO_BENCH_TOL.
-bench-check:
-	$(PY) tools/bench_check.py BENCH_serving.json BENCH_kernels.json
 
 example:
 	PYTHONPATH=src $(PY) examples/serve_partitioned.py

@@ -41,7 +41,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bench_io import BenchBundle
 from repro.kernels import ops, ref
 from repro.launch.compile_cache import use_compile_cache
 
@@ -343,41 +342,7 @@ def run() -> list[str]:
     return rows
 
 
-def _bundle(rows: list[str]) -> BenchBundle:
-    """Fold the CSV rows into a BENCH_kernels.json bundle.  All metrics
-    are wall-clock (interpret-mode kernels off-TPU), so everything lands
-    in ``timing``; the backend/mode ride along as cell config."""
-    backend = jax.default_backend()
-    mode = "compiled" if backend == "tpu" else "interpret"
-    config = dict(backend=backend, kernel_mode=mode, fast=FAST)
-    b = BenchBundle("kernels")
-    for r in rows:
-        if r.startswith("#"):
-            continue
-        parts = r.split(",")
-        if len(parts) == 4:  # name,shape,us_kernel,us_jnp
-            name, shape, us_k, us_j = parts
-            if name.startswith("heads/"):
-                # Batched-vs-sequential exit-head cells: the pair is
-                # (batched, sequential) and the speedup is the metric the
-                # PR gate reads.
-                b.cell(f"{name}/{shape}", config=config,
-                       timing=dict(us_batched=float(us_k),
-                                   us_sequential=float(us_j),
-                                   speedup=float(us_j) / float(us_k)))
-            else:
-                b.cell(f"{name}/{shape}", config=config,
-                       timing=dict(us_kernel=float(us_k), us_jnp=float(us_j)))
-        elif len(parts) == 3:  # name,us,impl (part-1 reference rows)
-            name, us, impl = parts
-            b.cell(name, config=dict(**config, impl=impl),
-                   timing=dict(us=float(us)))
-    return b
-
-
 if __name__ == "__main__":
     use_compile_cache()
-    all_rows = run()
-    for r in all_rows:
+    for r in run():
         print(r)
-    print(f"\nwrote {_bundle(all_rows).write()}")

@@ -138,10 +138,12 @@ def bench_partitioned_serving() -> list[str]:
         for i in range(5):
             rep, caches = srv.step(tok, i + 1, caches)
         dt = (time.perf_counter() - t0) / 5 * 1e6
+        res = rep.tier_result
         est = "-" if rep.est_latency_s is None else f"{rep.est_latency_s:.5f}"
         rows.append(
             f"serving/partitioned_split{split},{dt:.0f},"
-            f"shipped={rep.shipped}/8;bytes={rep.bytes_shipped:.0f};estT={est}"
+            f"shipped={sum(res.shipped_per_hop)}/8;"
+            f"bytes={sum(res.bytes_per_hop):.0f};estT={est}"
         )
     return rows
 

@@ -76,8 +76,8 @@ def main() -> None:
     shipped_total = 0
     for i in range(8):
         rep, caches = srv.step(tok, pos + i, caches)
-        tok = jnp.asarray(rep.tokens[:, None])
-        shipped_total += rep.shipped
+        tok = jnp.asarray(rep.tier_result.tokens[:, None])
+        shipped_total += sum(rep.tier_result.shipped_per_hop)
     print(f"partitioned decode: {shipped_total}/64 token-steps crossed the "
           f"cut (the rest exited on the edge)")
 

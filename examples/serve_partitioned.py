@@ -24,7 +24,11 @@ from repro.core import LayerCost, Partitioner, build_cost_profile
 from repro.core.multitier import TierSpec, solve_multitier
 from repro.core.types import NetworkProfile
 from repro.models import model as M
-from repro.serving import MultiTierServer, PartitionedServer, ServingEngine
+from repro.serving import (
+    PartitionedServer,
+    RepartitionController,
+    ServingEngine,
+)
 from repro.serving.tiers import bytes_per_sequence
 
 BATCH = 16
@@ -72,18 +76,21 @@ def main() -> None:
              for i in range(1, n + 1)]
 
     # ---- paper system: 2 tiers, repartitioned as bandwidth drifts.  The
-    # server is created once; set_split hot-swaps the cut and re-uses the
-    # compiled segment functions of any previously-installed split.
-    srv = PartitionedServer(cfg, params, 0)
+    # server is created once; the controller re-solves on each link change
+    # and hot-swaps the cut, re-using the compiled segment functions of any
+    # previously-installed split.
+    profile = build_cost_profile(
+        costs, cfg.branch_layers, p_k,
+        network=NetworkProfile(*NETWORK_SCHEDULE[0]),
+        gamma=25.0, raw_input_bytes=RAW_INPUT_BYTES,
+    )
+    srv = PartitionedServer(cfg, params, 0, cost_profile=profile)
+    ctl = RepartitionController(srv, profile)
+    ctl.update(stats)
     for net_name, bw in NETWORK_SCHEDULE:
-        profile = build_cost_profile(
-            costs, cfg.branch_layers, p_k,
-            network=NetworkProfile(net_name, bw),
-            gamma=25.0, raw_input_bytes=RAW_INPUT_BYTES,
-        )
-        plan = Partitioner(profile).solve()
-        srv.cost_profile = profile
-        srv.set_split(plan.split_layer)
+        (cut,) = ctl.update_network(NetworkProfile(net_name, bw))
+        plan = Partitioner(ctl.profile).solve()
+        assert plan.split_layer == cut
         print(f"\n== network {net_name} ({bw / 1e6:.2f} Mbps) -> {plan.describe()}")
 
         caches = M.init_caches(cfg, BATCH, CONTEXT)
@@ -93,9 +100,10 @@ def main() -> None:
         t0 = time.perf_counter()
         for i in range(DECODE_STEPS):
             rep, caches = srv.step(tok, PROMPT + i, caches)
-            tok = jnp.asarray(rep.tokens[:, None])
-            shipped += rep.shipped
-            edge_exits += int(rep.exited_on_edge.sum())
+            res = rep.tier_result
+            tok = jnp.asarray(res.tokens[:, None])
+            shipped += sum(res.shipped_per_hop)
+            edge_exits += int(res.exited.sum())
         dt = time.perf_counter() - t0
         total = BATCH * DECODE_STEPS
         print(
@@ -121,8 +129,8 @@ def main() -> None:
           f"tier_of_layer {plan3.tier_of_layer}, "
           f"E[T]={plan3.expected_time_s * 1e3:.2f} ms")
 
-    srv3 = MultiTierServer.from_plan(
-        cfg, params, plan3, tiers, cost=(profile.t_c, profile.alpha)
+    srv3 = PartitionedServer(
+        cfg, params, plan3.cut_after, tiers=tiers, cost_profile=profile
     )
     caches = M.init_caches(cfg, BATCH, CONTEXT)
     tok = jnp.zeros((BATCH, 1), jnp.int32)
@@ -130,15 +138,16 @@ def main() -> None:
     hop_shipped = np.zeros(len(tiers) - 1, int)
     for i in range(DECODE_STEPS):
         rep3, caches = srv3.step(tok, PROMPT + i, caches)
-        tok = jnp.asarray(rep3.tokens[:, None])
-        for j in range(len(rep3.bytes_per_hop)):
-            hop_bytes[j] += rep3.bytes_per_hop[j]
-            hop_shipped[j] += rep3.shipped_per_hop[j]
+        res3 = rep3.tier_result
+        tok = jnp.asarray(res3.tokens[:, None])
+        for j in range(len(res3.bytes_per_hop)):
+            hop_bytes[j] += res3.bytes_per_hop[j]
+            hop_shipped[j] += res3.shipped_per_hop[j]
 
-    # Per-hop byte accounting must match the installed MultiTierPlan: every
+    # Per-hop byte accounting must match the installed lattice plan: every
     # survivor crossing hop j carries the residual stream of the plan's cut
     # layer (alpha_{c_j}; a cut before layer 1 ships the 4-byte token id).
-    for j, cut in enumerate(srv3.cuts[: len(rep3.bytes_per_hop)]):
+    for j, cut in enumerate(srv3.cuts[: len(res3.bytes_per_hop)]):
         per_seq = bytes_per_sequence(cfg, cut)
         assert hop_bytes[j] == hop_shipped[j] * per_seq
         if cut > 0:
@@ -148,10 +157,10 @@ def main() -> None:
               f"over {tiers[j].uplink_bps / 1e6:.2f} Mbps "
               f"(matches plan alpha)")
     print(f"   last step est E[T]={rep3.est_latency_s * 1e3:.2f} ms/sample, "
-          f"exit tiers {np.bincount(rep3.exit_tier + 1, minlength=len(tiers) + 1)}")
+          f"exit tiers {np.bincount(res3.exit_tier + 1, minlength=len(tiers) + 1)}")
     # Survivor compaction: each downstream tier ran a dense sub-batch
     # padded to the bucket ladder, not the masked full batch.
-    for j, hop in enumerate(rep3.compaction):
+    for j, hop in enumerate(res3.compaction):
         print(f"   hop {j}: {hop.survivors} survivors -> bucket {hop.bucket} "
               f"({hop.padded_waste} padding rows), "
               f"{srv3.executor.overflow_retries} overflow retries total")
@@ -176,24 +185,23 @@ def main() -> None:
         TierSpec("cloud", 1.0),
     ]
     for overlap in ("serial", "pipelined"):
-        srvp = MultiTierServer(
-            cfg, params, sim_tiers, (2, 3),
-            cost=(profile.t_c, profile.alpha),
+        srvp = PartitionedServer(
+            cfg, params, (2, 3), tiers=sim_tiers, cost_profile=profile,
             simulate_network=True, overlap=overlap,
         )
         caches = M.init_caches(cfg, BATCH, CONTEXT)
         tok = jnp.zeros((BATCH, 1), jnp.int32)
         repp, caches = srvp.step(tok, PROMPT, caches)  # warm the jit
-        tok = jnp.asarray(repp.tokens[:, None])
+        tok = jnp.asarray(repp.tier_result.tokens[:, None])
         srvp.executor.drain()  # don't time the warmup step's transfers
         t0 = time.perf_counter()
         for i in range(1, DECODE_STEPS):
             repp, caches = srvp.step(tok, PROMPT + i, caches)
-            tok = jnp.asarray(repp.tokens[:, None])
+            tok = jnp.asarray(repp.tier_result.tokens[:, None])
         srvp.executor.drain()  # account the trailing in-flight transfers
         dt = (time.perf_counter() - t0) / (DECODE_STEPS - 1)
         print(f"   {overlap:<9} {dt * 1e3:7.1f} ms/step "
-              f"(sim transfers {tuple(round(s * 1e3) for s in repp.sim_transfer_s)} ms, "
+              f"(sim transfers {tuple(round(s * 1e3) for s in repp.tier_result.sim_transfer_s)} ms, "
               f"est E[T]/step {repp.est_latency_s * 1e3:.2f} ms)")
 
     # ---- continuous batching on the K=3 plan: a stream of requests with
@@ -201,9 +209,8 @@ def main() -> None:
     # submit()/drain() — finished/early-exited requests retire mid-flight
     # and waiting prompts prefill into the freed KV rows, so nobody waits
     # for a lock-step wave to drain.
-    srvr = MultiTierServer(
-        cfg, params, tiers, plan3.cut_after,
-        cost=(profile.t_c, profile.alpha),
+    srvr = PartitionedServer(
+        cfg, params, plan3.cut_after, tiers=tiers, cost_profile=profile,
         slots=6, context_len=CONTEXT,
     )
     rng = np.random.default_rng(0)
@@ -247,16 +254,15 @@ def main() -> None:
     # The controller ingests the breaker event and re-solves with the
     # hop's availability at 0 — the new cuts ship nothing across it.
     from repro.serving import (
-        FlapWindow, HopPolicy, LinkFaultModel, RepartitionController,
-        RequestScheduler,
+        FlapWindow, HopPolicy, LinkFaultModel, RequestScheduler,
     )
     fault_tiers = [
         TierSpec("edge", 12.0, uplink_bps=18.8e6),
         TierSpec("mid", 4.0, uplink_bps=5.85e6),
         TierSpec("cloud", 1.0),
     ]
-    srvf = MultiTierServer(
-        cfg, params, fault_tiers, (1, 3), simulate_network=True,
+    srvf = PartitionedServer(
+        cfg, params, (1, 3), tiers=fault_tiers, simulate_network=True,
         slots=6, context_len=CONTEXT,
         fault_model=LinkFaultModel(
             seed=0, flaps=(FlapWindow(hop=1, start_step=6, end_step=10_000),)

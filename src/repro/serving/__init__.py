@@ -2,8 +2,10 @@
 
     TierExecutor / TierSegment   device-resident exit/transfer core
     ServingEngine                K=1 (monolithic, calibration source)
-    PartitionedServer            K=2 (the paper's edge/cloud system)
-    MultiTierServer              K>=3 (lattice plans from core.multitier)
+    PartitionedServer            K>=2 tiers over a plan of cuts (an int
+                                 cut is the paper's edge/cloud system)
+    StepReport                   one decode step: the executor's
+                                 TierStepResult + the server's estimate
     RepartitionController        live p_k -> solver -> hot swap
     RequestScheduler             continuous-batching request lifecycle
                                  (submit/run/drain over recycled KV slots)
@@ -21,8 +23,7 @@ from repro.serving.faults import (
     LinkDownError,
     LinkFaultModel,
 )
-from repro.serving.multitier import MultiTierServer, MultiTierStepReport
-from repro.serving.partitioned import PartitionedServer, StepReport
+from repro.serving.partitioned import PartitionedServer
 from repro.serving.scheduler import (
     Request,
     RequestResult,
@@ -31,6 +32,7 @@ from repro.serving.scheduler import (
 )
 from repro.serving.tiers import (
     HopCompaction,
+    StepReport,
     TierExecutor,
     TierSegment,
     TierStepResult,
@@ -43,8 +45,6 @@ __all__ = [
     "ServingEngine",
     "PartitionedServer",
     "StepReport",
-    "MultiTierServer",
-    "MultiTierStepReport",
     "RepartitionController",
     "Request",
     "RequestResult",

@@ -6,7 +6,7 @@ partition optimizer whenever they (or the network) drift.  This module
 closes that loop against the unified tier runtime:
 
     ExitStats.conditional_probs() -> Partitioner / solve_multitier
-        -> PartitionedServer.set_split / MultiTierServer.install_cuts
+        -> PartitionedServer.install_cuts
 
 Three triggers are supported:
 
@@ -16,15 +16,19 @@ Three triggers are supported:
     measured exit distribution against the one the installed plan was
     solved for and re-solves when the KL divergence exceeds
     ``kl_threshold`` (``None`` = re-solve on every check);
-  * **network** — ``update_network(profile)`` / ``update_tiers(specs)``
-    re-solve with the last measured probabilities when the link changes.
+  * **network** — ``update_tiers(specs)`` (any K) / ``update_network(
+    profile)`` (its K=2 form: the one hop's link) re-solve with the last
+    measured probabilities when a link changes.
 
 Swaps go through ``TierExecutor.install``, which re-uses the compiled
 function of every tier segment whose (layer range, branches) is unchanged
-— repartitioning never pays a full re-jit.  When ``batch`` is set and the
-server compacts, solves (K=2 and K>=3 alike) use the bucketed lattice
-cost (core.multitier) so the plan is honest about the compacted runtime's
-padding waste.  A server running
+— repartitioning never pays a full re-jit.  Solves price the server's
+plan (its ``tiers``) with the cost model
+:func:`~repro.serving.partitioned.paper_route` picks from what the plan
+is — the paper's Dijkstra for a serial, unbatched K=2 plan over a healthy
+hop, the lattice (core.multitier) otherwise.  When ``batch`` is set and
+the server compacts, the lattice is the bucketed cost, honest about the
+compacted runtime's padding waste.  A server running
 ``overlap="pipelined"`` is re-solved against the pipeline-bottleneck
 steady-state cost (``overlap=True`` in core.multitier) — the optimal cut
 generally moves when transfers overlap compute.
@@ -45,9 +49,9 @@ availability (success fraction of *attempted* hops) and observed
 transfer seconds — and, on a breaker state change (a hop's circuit
 opening or closing), immediately re-solves with each hop's ``TierSpec``
 availability set from the EWMA (0 for a breaker-open hop, which the
-solver prices as an unusable link).  The re-solve goes through
-``update_tiers`` / the lattice route, so the drift window resets and the
-new plan moves the cut off the sick hop.  A hop the breaker skipped is
+solver prices as an unusable link).  The re-solve prices a degraded
+hop on the lattice and resets the drift window; the new plan moves the
+cut off the sick hop.  A hop the breaker skipped is
 *not* an observation (no EWMA update), and a failed half-open probe
 updates availability only — never the transfer-time EWMA — so breaker
 probing cannot corrupt the cost estimates.  ``fault_resolve=False``
@@ -65,8 +69,11 @@ from repro.core.multitier import TierSpec, solve_multitier
 from repro.core.partitioner import Partitioner
 from repro.core.types import CostProfile, NetworkProfile
 from repro.serving.engine import ExitStats
-from repro.serving.multitier import MultiTierServer
-from repro.serving.partitioned import PartitionedServer
+from repro.serving.partitioned import (
+    PartitionedServer,
+    paper_profile,
+    paper_route,
+)
 
 __all__ = ["RepartitionController", "exit_distribution", "exit_drift_kl"]
 
@@ -98,14 +105,18 @@ def exit_drift_kl(
 @dataclasses.dataclass
 class RepartitionController:
     """Feeds measured ``p_k`` back through the solver and installs the
-    result on a 2-tier or K-tier server."""
+    result on the server's K-tier plan.  ``profile`` gives the per-layer
+    times and sizes; ``tiers`` the tiers priced.  By default they are the
+    server's, except that a K=2 plan's edge prices the profile's own
+    ``gamma`` and ``network`` (the paper's contract: the profile states
+    the link a solve is for)."""
 
-    server: PartitionedServer | MultiTierServer
+    server: PartitionedServer
     profile: CostProfile
-    tiers: list[TierSpec] | None = None  # required for MultiTierServer
+    tiers: list[TierSpec] | None = None
     kl_threshold: float | None = None  # drift gate for observe()-driven solves
     every_n_steps: int = 0  # decode-loop hook cadence (0 = explicit only)
-    batch: int | None = None  # bucketed-aware solving (K=2 and K>=3)
+    batch: int | None = None  # priced when the server compacts (bucketed)
     window_steps: int = 256  # drift-window decay horizon (see observe())
     # Epsilon exploration schedule: every ``explore_every_n`` observed
     # steps, request a PROBE step from the executor — the next decode step
@@ -131,8 +142,14 @@ class RepartitionController:
     fault_resolve: bool = True
 
     def __post_init__(self):
-        if isinstance(self.server, MultiTierServer) and self.tiers is None:
+        if self.tiers is None:
             self.tiers = list(self.server.tiers)
+            if len(self.tiers) == 2:
+                self.tiers[0] = dataclasses.replace(
+                    self.tiers[0], gamma=self.profile.gamma,
+                    uplink_bps=self.profile.network.bandwidth_bps,
+                )
+        self.tiers = list(self.tiers)
         k = len(self.server.cfg.branch_layers)
         # Per-branch (arrivals, exits) over the current window.  A branch
         # the installed plan never evaluates (discarded at a cut, or inside
@@ -177,58 +194,46 @@ class RepartitionController:
             return None
         return float(min(max(occ, 1e-6), 1.0))
 
+    def _priced_tiers(self) -> list[TierSpec]:
+        """The plan's tiers with each hop's availability from the health
+        EWMAs where observed (0 for a breaker-open hop)."""
+        last = len(self.tiers) - 1
+        return [
+            t if j == last else dataclasses.replace(
+                t,
+                availability=(
+                    0.0 if j in self._hop_open
+                    else self._hop_avail.get(j, t.availability)
+                ),
+            )
+            for j, t in enumerate(self.tiers)
+        ]
+
     def solve(self, p_k: np.ndarray) -> tuple[int, ...]:
-        """Optimal cut vector for the profile with live exit probs.  A
-        server running ``overlap="pipelined"`` is solved against the
-        pipeline-bottleneck steady-state cost (the optimal cut can move
-        under overlap), a serial server against the serial chain sum.
-        Batched solves price the occupancy-weighted steady-state live
-        width (see ``occupancy``)."""
+        """Optimal cut vector for the profile with live exit probs, priced
+        by the model :func:`paper_route` picks.  A server running
+        ``overlap="pipelined"`` is solved against the pipeline-bottleneck
+        steady-state cost (the optimal cut can move under overlap), a
+        serial server against the serial chain sum.  Batched solves price
+        the occupancy-weighted steady-state live width (see
+        ``occupancy``); a mesh-sharded tier's shard width and interconnect
+        ride in its ``TierSpec``, and a degraded hop's availability pushes
+        the cut toward keeping work before it."""
         prof = Partitioner(self.profile).with_exit_probs(p_k).profile
-        overlap = getattr(self.server, "overlap", "serial") == "pipelined"
-        occ = self._solve_occupancy()
-        if isinstance(self.server, MultiTierServer):
-            plan = solve_multitier(
-                prof.t_c, prof.alpha, prof.branch_exit_probs(), self.tiers,
-                batch=self.batch,
-                overlap=overlap,
-                occupancy=occ if self.batch is not None else None,
-            )
-            return plan.cut_after
-        bucketed = (
-            self.batch is not None
-            and getattr(self.server, "compaction", "off") == "bucketed"
+        p = prof.branch_exit_probs()
+        tiers = self._priced_tiers()
+        overlap = self.server.overlap == "pipelined"
+        batch = self.batch if self.server.compaction == "bucketed" else None
+        if paper_route(tiers, overlap, batch):
+            plan = Partitioner(paper_profile(prof, tiers[0])).solve()
+            return (plan.split_layer,)
+        plan = solve_multitier(
+            prof.t_c, prof.alpha, p, tiers,
+            batch=batch,
+            overlap=overlap,
+            occupancy=self._solve_occupancy() if batch is not None else None,
         )
-        avail = 0.0 if 0 in self._hop_open else self._hop_avail.get(0, 1.0)
-        if overlap or bucketed or avail < 1.0:
-            # 2-tier pipelined and/or bucketed: the paper's Dijkstra
-            # minimizes the ideal serial sum; route through the unified
-            # lattice cost instead so the installed cut optimizes the same
-            # objective the server's est_latency_s reports (bottleneck
-            # stage under overlap, padding-honest under compaction).  The
-            # lattice model (like the paper's Eq. 5) neglects branch-head
-            # compute, so a profile with include_branch_compute=True is
-            # optimized without the gamma * t_b edge terms here.  A
-            # mesh-sharded server's shard widths / interconnect carry into
-            # the specs so re-solves price the sharded cloud tier.
-            # Degraded uplink health routes the 2-tier solve through the
-            # lattice as well: the edge spec carries the EWMA availability
-            # (0 = breaker open), which _hop_seconds prices as a slower —
-            # or unusable — link, pushing the cut toward all-edge.
-            dev = getattr(self.server, "tier_devices", None) or (1, 1)
-            ici = getattr(self.server, "ici_bps", 0.0)
-            tiers = [
-                TierSpec("edge", prof.gamma, prof.network.bandwidth_bps,
-                         devices=dev[0], ici_bps=ici, availability=avail),
-                TierSpec("cloud", 1.0, devices=dev[1], ici_bps=ici),
-            ]
-            plan = solve_multitier(
-                prof.t_c, prof.alpha, prof.branch_exit_probs(), tiers,
-                batch=self.batch if bucketed else None, overlap=overlap,
-                occupancy=occ if bucketed else None,
-            )
-            return plan.cut_after
-        return (Partitioner(prof).solve().split_layer,)
+        return plan.cut_after
 
     def _install(self, p_k: np.ndarray) -> tuple[int, ...]:
         cuts = self.solve(p_k)
@@ -239,11 +244,8 @@ class RepartitionController:
         self._arrivals[:] = 0
         self._exits[:] = 0
         self._window_age = 0
-        if isinstance(self.server, MultiTierServer):
-            self.server.install_cuts(cuts)
-            return self.server.cuts
-        self.server.set_split(cuts[0])
-        return (self.server.split_layer,)
+        self.server.install_cuts(cuts)
+        return self.server.cuts
 
     def update(self, stats: ExitStats) -> tuple[int, ...]:
         """Re-solve from live stats and hot-swap the split if it moved.
@@ -395,28 +397,17 @@ class RepartitionController:
         Fires automatically on breaker state changes when
         ``fault_resolve`` is set; callable explicitly otherwise.
 
-        The K>=3 path goes through :meth:`update_tiers`, so the drift
-        window resets exactly as it does for any topology change.  Note
+        The server's tier specs take the new availabilities (its estimate
+        prices them); the drift window resets as for any re-solve.  Note
         that once the re-solve moves the cut off a sick hop, that hop is
         no longer exercised — its breaker never half-opens again, so
         recovery needs an explicit ``update_tiers`` with the restored
         specs (or ``fault_resolve=False`` with manual control)."""
         self.fault_resolves += 1
-        if isinstance(self.server, MultiTierServer):
-            specs = [
-                dataclasses.replace(
-                    t,
-                    availability=(
-                        0.0 if j in self._hop_open
-                        else self._hop_avail.get(j, t.availability)
-                    ),
-                )
-                if j < len(self.tiers) - 1 else t
-                for j, t in enumerate(self.tiers)
-            ]
-            return self.update_tiers(specs)
-        # 2-tier: availability reaches the solve through the lattice route
-        # (see solve()); segments are unchanged, so no executor refresh.
+        self.server.tiers = tuple(
+            dataclasses.replace(t, availability=priced.availability)
+            for t, priced in zip(self.server.tiers, self._priced_tiers())
+        )
         return self._install(self._best_p())
 
     def measured_probs(self) -> np.ndarray:
@@ -454,27 +445,30 @@ class RepartitionController:
 
     # ------------------------------------------------------ network drift
     def update_network(self, network: NetworkProfile) -> tuple[int, ...]:
-        """The 2-tier link changed: re-solve with the last measured (or
-        installed) exit probs against the new bandwidth."""
-        if not isinstance(self.server, PartitionedServer):
-            raise TypeError("update_network is 2-tier; use update_tiers for K>=3")
+        """The K=2 form of :meth:`update_tiers`: the one hop's link
+        changed.  Re-solve with the last measured (or installed) exit
+        probs against the new bandwidth."""
+        if len(self.tiers) != 2:
+            raise ValueError(
+                f"update_network re-prices the one hop of a K=2 plan; this "
+                f"plan has {len(self.tiers)} tiers: use update_tiers"
+            )
         self.profile = dataclasses.replace(self.profile, network=network)
-        self.server.network = network
         if self.server.cost_profile is not None:
             self.server.cost_profile = self.profile
-        cuts = self._install(self._best_p())
-        # Refresh segments even when the cut didn't move: the new uplink
-        # must reach the executor's per-hop byte/latency accounting.
-        self.server.executor.install(self.server._segments(self.server.split_layer))
-        return cuts
+        edge, cloud = self.tiers
+        return self.update_tiers([
+            dataclasses.replace(edge, uplink_bps=network.bandwidth_bps), cloud
+        ])
 
     def update_tiers(self, tiers: list[TierSpec]) -> tuple[int, ...]:
-        """K>=3 tier topology / uplinks changed: re-solve and hot-swap."""
-        if not isinstance(self.server, MultiTierServer):
-            raise TypeError("update_tiers is K>=3; use update_network for 2-tier")
+        """The plan's tier specs (uplinks, speeds, availability) changed:
+        re-solve and hot-swap."""
         self.tiers = list(tiers)
         self.server.tiers = tuple(tiers)
         cuts = self._install(self._best_p())
+        # Refresh segments even when the cuts didn't move: the new uplinks
+        # must reach the executor's per-hop byte/latency accounting.
         self.server.executor.install(self.server._segments(self.server.cuts))
         return cuts
 

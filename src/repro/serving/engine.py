@@ -27,7 +27,7 @@ from repro.core.calibration import calibrate_exit_probs
 from repro.launch.mesh import mesh_devices
 from repro.models import model as M
 from repro.serving.scheduler import ServesRequests
-from repro.serving.tiers import TierExecutor, TierStepResult, segments_for_cuts
+from repro.serving.tiers import StepReport, TierExecutor, segments_for_cuts
 
 __all__ = ["ServingEngine", "ExitStats"]
 
@@ -105,11 +105,16 @@ class ServingEngine(ServesRequests):
 
     def step(
         self, tok: jax.Array, pos, caches: Any, *, active=None
-    ) -> tuple[TierStepResult, Any]:
+    ) -> tuple[StepReport, Any]:
         """One fused decode step (the K=1 tier configuration); ``pos`` may
         be per-sequence and ``active`` masks dead request slots — the
         entry points the request scheduler drives."""
-        return self._exec.step(tok, pos, caches, active=active)
+        res, caches = self._exec.step(tok, pos, caches, active=active)
+        return StepReport(
+            res,
+            overflow_retries=self._exec.overflow_retries,
+            pipeline_fallbacks=self._exec.pipeline_fallbacks,
+        ), caches
 
     def start(self, inputs: dict) -> dict:
         """Prefill a batch of prompts; returns mutable serve state."""

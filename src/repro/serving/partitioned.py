@@ -1,30 +1,31 @@
-"""Partitioned (edge/cloud) BranchyNet serving — the paper's system.
+"""Partitioned BranchyNet serving over a K-tier plan — the paper's system
+and its K >= 3 generalization on one server.
 
-A thin 2-tier configuration of :class:`~repro.serving.tiers.TierExecutor`.
-One decode step splits at the plan's partition layer ``v_s``:
+A plan is ``cuts`` (K-1 layers after which a hop happens) and ``tiers``
+(K :class:`~repro.core.multitier.TierSpec`).  One decode step runs the
+:class:`~repro.serving.tiers.TierExecutor` over the segments the plan
+cuts: tier j runs trunk layers ``(c_j, c_{j+1}]`` and the side branches
+strictly inside them; sequences whose branch entropy clears the threshold
+*exit* there and are never shipped (the ``surv(s) * t_s^net`` term of the
+paper's Eq. 5); survivors cross each hop with per-hop byte accounting.
 
-  edge tier : embed + trunk layers [0, s) + the side branches before the
-              cut.  Sequences whose branch entropy clears the threshold
-              *exit on the edge* — they emit a token immediately and are
-              never shipped (this is exactly the mechanism that makes the
-              expected transfer cost ``surv(s) * t_s^net`` in Eq. 5).
-  transfer  : the residual stream (B_surviving, 1, d_model) crosses the
-              bandwidth cliff; we account bytes and model latency with the
-              paper's cost model.
-  cloud tier: trunk layers [s, L) + final head for surviving sequences.
+A single int ``cuts`` is the paper's K=2 edge/cloud split ``v_s``
+(0 = cloud-only, L = edge-only); its two tiers are derived from
+``network``, ``cost_profile``, ``tier_devices`` and ``ici_bps``.  A tuple
+with ``tiers=`` is any K (a ``core.multitier`` lattice plan).
 
-On one host this is a simulation of the two tiers (both run locally), but
-the tier boundary is real in the compiled program: edge/cloud are two
-separate jitted segment functions with an explicit tensor handoff, which
-is the same structure a real edge deployment lowers.  ``set_split`` swaps
-the cut at runtime; a segment whose (layer range, branches) is unchanged
-re-uses its compiled function.
+On one host this is a simulation of the tiers (all run locally), but the
+tier boundary is real in the compiled program: each tier is a separate
+jitted segment function with an explicit tensor handoff, the same
+structure a real deployment lowers.  ``install_cuts`` swaps the cuts at
+runtime; a segment whose (layer range, branches) is unchanged re-uses its
+compiled function.
 """
 
 from __future__ import annotations
 
 import dataclasses
-from typing import Any
+from typing import Any, Sequence
 
 import jax
 import numpy as np
@@ -32,59 +33,71 @@ import numpy as np
 from repro.configs.base import ModelConfig
 from repro.core.latency import expected_time
 from repro.core.multitier import TierSpec, expected_time_multitier
+from repro.core.partitioner import Partitioner
 from repro.core.profiler import branch_head_cost
 from repro.core.types import CostProfile, NetworkProfile
 from repro.launch.mesh import mesh_devices
 from repro.serving.scheduler import ServesRequests
 from repro.serving.tiers import (
-    HopCompaction,
+    StepReport,
     TierExecutor,
     TierStepResult,
     segments_for_cuts,
+    transfer_seconds,
 )
 
-__all__ = ["PartitionedServer", "StepReport"]
+__all__ = ["PartitionedServer", "paper_profile", "paper_route"]
 
 
-@dataclasses.dataclass
-class StepReport:
-    tokens: np.ndarray  # (B,)
-    exited_on_edge: np.ndarray  # (B,) bool
-    shipped: int  # sequences that crossed the cut
-    bytes_shipped: float
-    est_latency_s: float | None  # paper Eq. 5 with the measured exit fraction
-    compaction: tuple[HopCompaction, ...] = ()  # cloud sub-batch shape
-    branch_take: dict[int, np.ndarray] = dataclasses.field(default_factory=dict)
-    sim_transfer_s: tuple[float, ...] = ()  # simulated uplink wall time
-    # Cumulative executor health counters (bucket-policy observability):
-    # steps re-run on bucket overflow, and pipelined steps that fell back
-    # to serial because of one.
-    overflow_retries: int = 0
-    pipeline_fallbacks: int = 0
-    #: Live request slots this step decoded (== B under lock-step); the
-    #: estimator prices the steady-state live width through it.
-    live: int = 0
-    #: The executor's raw result (per-slot tokens_dev / exit_tier / probe
-    #: coverage) — what the request scheduler and controller consume.
-    tier_result: TierStepResult | None = None
-    #: Fault-plane outputs (serving.tiers degraded-step contract): rows
-    #: finalized from the edge fallback head / rows that could not emit,
-    #: the step's replayable fault trace, and the broken hop (None =
-    #: healthy step).
-    degraded: np.ndarray | None = None
-    failed: np.ndarray | None = None
-    fault_events: tuple = ()
-    degraded_hop: int | None = None
+def paper_route(
+    tiers: Sequence[TierSpec], overlap: bool, batch: int | None
+) -> bool:
+    """Which cost model prices a plan: the one rule the server's estimate
+    and :meth:`RepartitionController.solve` share.
+
+    The paper's Eq. 5 (estimate) / Dijkstra (solve) price a K=2 plan run
+    serially, unbatched (``batch`` is None unless the runtime compacts and
+    a batch is priced) over a healthy hop.  The lattice of
+    :mod:`repro.core.multitier` prices every other plan: K >= 3, pipelined
+    overlap (the bottleneck stage), bucketed compaction (padding-honest),
+    a degraded hop (availability).  Where both could apply they agree,
+    except that the lattice leaves out branch-head compute: a profile with
+    ``include_branch_compute=True`` is priced with the ``gamma * t_b``
+    edge terms here and without them on the lattice."""
+    return (
+        len(tiers) == 2
+        and not overlap
+        and batch is None
+        and tiers[0].availability >= 1.0
+    )
+
+
+def paper_profile(profile: CostProfile, edge: TierSpec) -> CostProfile:
+    """``profile`` pricing the edge tier's gamma and uplink (the K=2
+    plan's own link)."""
+    return dataclasses.replace(
+        profile,
+        gamma=edge.gamma,
+        network=dataclasses.replace(
+            profile.network, bandwidth_bps=edge.uplink_bps
+        ),
+    )
 
 
 @dataclasses.dataclass
 class PartitionedServer(ServesRequests):
     cfg: ModelConfig
     params: Any
-    split_layer: int  # the plan's v_s (0 = cloud-only, L = edge-only)
-    network: NetworkProfile | None = None
-    cost_profile: CostProfile | None = None  # for latency estimates
-    compaction: str = "bucketed"  # "off" = legacy masked full-batch cloud
+    cuts: int | Sequence[int]  # layer after which each hop happens (K-1,)
+    # The plan's K tiers; None = the paper's edge/cloud pair, derived once
+    # from ``cost_profile`` and the init-only ``network`` /
+    # ``tier_devices`` / ``ici_bps`` (``tiers`` is then the one record).
+    tiers: Sequence[TierSpec] | None = None
+    network: dataclasses.InitVar[NetworkProfile | None] = None  # K=2 uplink
+    # Per-layer times and sizes for est_latency_s (None = no estimate); its
+    # gamma and network price the derived K=2 edge tier.
+    cost_profile: CostProfile | None = None
+    compaction: str = "bucketed"  # "off" = legacy masked full-batch tiers
     simulate_network: bool = False  # sleep each hop's transfer time
     overlap: str = "serial"  # "pipelined" = overlap transfers with compute
     use_kernels: bool | None = None  # Pallas decode path; None = cfg/auto
@@ -103,30 +116,43 @@ class PartitionedServer(ServesRequests):
     bucket_headroom: float = 0.0  # fractional bucket padding vs retries
     slots: int = 8  # request-scheduler KV slots (submit/run/drain API)
     context_len: int = 4096  # scheduler cache capacity per slot
-    # Device mesh (+ optional explicit ShardingPolicy): the cloud tier is
-    # a mesh slice, not a chip — segments run SPMD (serving.tiers
-    # "Mesh-sharded tier segments").  ``tier_devices`` is the (edge,
-    # cloud) shard width the estimator prices (None = derive (1, mesh
-    # size) from the mesh); ``ici_bps`` the cloud tier's intra-mesh
-    # interconnect for its collective term.
+    # Device mesh (+ optional explicit ShardingPolicy): a tier is a mesh
+    # slice, not a chip — segments run SPMD (serving.tiers "Mesh-sharded
+    # tier segments").  Which tier is priced as sharded lives in each
+    # TierSpec's ``devices`` / ``ici_bps``; for the derived K=2 pair,
+    # ``tier_devices`` is the (edge, cloud) shard width (None = (1, mesh
+    # size)) and ``ici_bps`` the intra-mesh interconnect.
     mesh: Any = None
     sharding: Any = None
-    tier_devices: tuple[int, int] | None = None
-    ici_bps: float = 0.0
-    # Fault plane (serving.faults): a seeded LinkFaultModel arms uplink
-    # fault injection + breaker-gated retries + edge-head degradation;
+    tier_devices: dataclasses.InitVar[tuple[int, int] | None] = None
+    ici_bps: dataclasses.InitVar[float] = 0.0
+    # Fault plane (serving.faults): a seeded LinkFaultModel arms hop
+    # fault injection + breaker-gated retries + exit-head degradation;
     # hop_policy overrides the retry/timeout/breaker defaults.
     fault_model: Any = None
     hop_policy: Any = None
 
-    def __post_init__(self):
-        if self.tier_devices is None:
-            self.tier_devices = (
-                (1, mesh_devices(self.mesh)) if self.mesh is not None
-                else (1, 1)
+    def __post_init__(self, network, tier_devices, ici_bps):
+        cuts = (self.cuts,) if np.ndim(self.cuts) == 0 else self.cuts
+        self.cuts = tuple(int(c) for c in cuts)
+        if self.tiers is None:
+            if len(self.cuts) != 1:
+                raise ValueError(f"cuts {self.cuts} need tiers=")
+            devices = tier_devices or (
+                1, mesh_devices(self.mesh) if self.mesh is not None else 1
             )
+            prof = self.cost_profile
+            network = network or (prof.network if prof else None)
+            self.tiers = (
+                TierSpec("edge", prof.gamma if prof else 1.0,
+                         network.bandwidth_bps if network else 0.0,
+                         devices=devices[0], ici_bps=ici_bps),
+                TierSpec("cloud", 1.0, devices=devices[1], ici_bps=ici_bps),
+            )
+        self.tiers = tuple(self.tiers)
+        self._check(self.cuts)
         self.executor = TierExecutor(
-            self.cfg, self.params, self._segments(self.split_layer),
+            self.cfg, self.params, self._segments(self.cuts),
             compaction=self.compaction,
             simulate_network=self.simulate_network,
             overlap=self.overlap,
@@ -141,107 +167,90 @@ class PartitionedServer(ServesRequests):
         )
         self.params = self.executor.params
 
-    def _segments(self, s: int):
+    def _check(self, cuts: tuple[int, ...]) -> None:
+        if len(cuts) != len(self.tiers) - 1:
+            raise ValueError(
+                f"{len(self.tiers)} tiers need {len(self.tiers) - 1} cuts, "
+                f"got {cuts}"
+            )
+
+    def _segments(self, cuts: tuple[int, ...]):
         return segments_for_cuts(
-            self.cfg, (s,), names=("edge", "cloud"),
-            uplinks=(self.network.bandwidth_bps,) if self.network else None,
-            devices=self.tier_devices,
+            self.cfg, cuts,
+            names=tuple(t.name for t in self.tiers),
+            uplinks=tuple(t.uplink_bps for t in self.tiers),
+            devices=tuple(t.devices for t in self.tiers),
         )
 
-    def set_split(self, split_layer: int) -> None:
-        """Hot-swap the cut; unchanged tier segments are not re-jitted."""
-        if split_layer == self.split_layer:
+    def install_cuts(self, cuts: Sequence[int]) -> None:
+        """Hot-swap the hop points; unchanged tier segments keep their
+        compiled functions (no re-jit)."""
+        cuts = tuple(int(c) for c in cuts)
+        self._check(cuts)
+        if cuts == self.cuts:
             return
-        self.executor.install(self._segments(split_layer))
-        self.split_layer = split_layer
+        self.executor.install(self._segments(cuts))
+        self.cuts = cuts
 
     # ------------------------------------------------------------------
     def step(
         self, tok: jax.Array, pos, caches: Any, *, active=None
     ) -> tuple[StepReport, Any]:
         res, caches = self.executor.step(tok, pos, caches, active=active)
-        shipped = res.shipped_per_hop[0] if res.shipped_per_hop else 0
-        nbytes = res.bytes_per_hop[0] if res.bytes_per_hop else 0.0
         rep = StepReport(
-            tokens=res.tokens,
-            exited_on_edge=res.exited,
-            shipped=shipped,
-            bytes_shipped=nbytes,
-            est_latency_s=self._estimate(self.split_layer, res),
-            compaction=res.compaction,
-            branch_take=res.branch_take,
-            sim_transfer_s=res.sim_transfer_s,
+            tier_result=res,
+            est_latency_s=self._estimate(res),
+            transfer_s_per_hop=tuple(
+                transfer_seconds(nb, t.uplink_bps)
+                for nb, t in zip(res.bytes_per_hop, self.tiers)
+            ),
             overflow_retries=self.executor.overflow_retries,
             pipeline_fallbacks=self.executor.pipeline_fallbacks,
-            live=res.live,
-            tier_result=res,
-            degraded=res.degraded,
-            failed=res.failed,
-            fault_events=res.fault_events,
-            degraded_hop=res.degraded_hop,
         )
         return rep, caches
 
-    def _estimate(self, s: int, res) -> float | None:
-        """Paper Eq. 5 evaluated at this split with the *measured*
-        per-branch conditional exit probabilities substituted for p
-        (closing the calibration loop).
+    def _estimate(self, res: TierStepResult) -> float | None:
+        """The plan's expected step time at the installed cuts, with the
+        step's *measured* per-branch conditional exit probabilities
+        substituted for p (closing the calibration loop).
 
-        Each branch's conditional probability is derived from this step's
-        first-exit masks (``res.branch_take``) the same way
-        ``MultiTierServer._estimate`` does: exits at a branch over the
-        sequences still alive when they reached it.  (Substituting the
-        *cumulative* exit fraction for every branch — the historical
-        behavior — double-counts exits as soon as the plan evaluates two or
-        more branches.)  A branch the installed plan never evaluates
-        (discarded at the cut, or downstream of it) reads p = 0: that is
-        the probability the executed plan actually experiences.
-
-        When the runtime compacts (``compaction="bucketed"``) or pipelines
-        (``overlap="pipelined"``) the estimate uses the unified lattice
-        cost so K=2 reports the same padding-honest / bottleneck-stage
-        numbers as MultiTierServer rather than the ideal serial
-        ``surv(s) * B`` cloud term.  Under continuous batching the step's
-        live width feeds the occupancy term, so the estimate prices the
-        *steady-state* live batch rather than the nominal one."""
-        if self.cost_profile is None:
-            return None
+        A branch's conditional probability is its exits over the
+        sequences still alive when they reached it (``res.branch_take``,
+        in layer order); a branch the installed plan never evaluates reads
+        p = 0, the probability the executed plan experiences.  The cost
+        model is the one :func:`paper_route` picks.  On the lattice, a
+        compacting runtime is priced padding-honest at the step's batch, a
+        pipelined one at its bottleneck stage, and under continuous
+        batching the step's live width feeds the occupancy term."""
         prof = self.cost_profile
+        if prof is None:
+            return None
         batch = res.tokens.shape[0]
-        live = getattr(res, "live", 0) or batch
-        if prof.branches:
-            alive = float(live)
-            measured: dict[int, float] = {}
-            for layer in sorted(res.branch_take):
-                took = float(res.branch_take[layer].sum())
-                measured[layer] = took / alive if alive > 0 else 0.0
-                alive -= took
-            branches = tuple(
-                dataclasses.replace(b, exit_prob=measured.get(b.after_layer, 0.0))
-                for b in prof.branches
-            )
-            prof = dataclasses.replace(prof, branches=branches)
-        pipelined = self.overlap == "pipelined"
+        live = res.live or batch
+        p = np.zeros(len(prof.t_c))
+        alive = float(live)
+        for layer in sorted(res.branch_take):
+            took = float(res.branch_take[layer].sum())
+            p[layer] = took / alive if alive > 0 else 0.0
+            alive -= took
         bucketed = self.compaction == "bucketed"
-        if (bucketed or pipelined) and prof.network is not None:
-            tiers = [
-                TierSpec("edge", prof.gamma, prof.network.bandwidth_bps,
-                         devices=self.tier_devices[0], ici_bps=self.ici_bps),
-                TierSpec("cloud", 1.0,
-                         devices=self.tier_devices[1], ici_bps=self.ici_bps),
-            ]
-            head_cost = (
-                branch_head_cost(
-                    self.cfg, batch, heads_batched=self.heads_batched
-                )
-                if self.price_heads else None
+        overlap = self.overlap == "pipelined"
+        if paper_route(self.tiers, overlap, batch if bucketed else None):
+            prof = Partitioner(prof).with_exit_probs(
+                [p[b.after_layer] for b in prof.branches]
+            ).profile
+            return expected_time(
+                paper_profile(prof, self.tiers[0]), self.cuts[0]
             )
-            return expected_time_multitier(
-                prof.t_c, prof.alpha, prof.branch_exit_probs(), tiers, (s,),
-                batch=batch if bucketed else None,
-                overlap=pipelined,
-                occupancy=live / batch if bucketed else None,
-                head_cost=head_cost,
-                branch_layers=self.cfg.branch_layers,
-            )
-        return expected_time(prof, s)
+        head_cost = (
+            branch_head_cost(self.cfg, batch, heads_batched=self.heads_batched)
+            if self.price_heads else None
+        )
+        return expected_time_multitier(
+            prof.t_c, prof.alpha, p, list(self.tiers), self.cuts,
+            batch=batch if bucketed else None,
+            overlap=overlap,
+            occupancy=live / batch if bucketed else None,
+            head_cost=head_cost,
+            branch_layers=self.cfg.branch_layers,
+        )

@@ -137,7 +137,7 @@ class SchedulerStepReport:
     retired: tuple[int, ...]  # rids retired after the step
     emitted: dict[int, int]  # rid -> token decoded this step
     occupancy: float = 0.0  # live / slots
-    server_report: Any = None  # the underlying server/tier step report
+    server_report: Any = None  # the server's StepReport
     #: rids whose token this step came from a fallback exit head
     #: (degraded step) and rids whose slot hit an unrecoverable fault
     #: (retired failed, or re-queued when ``requeue_on_fail``).
@@ -148,10 +148,11 @@ class SchedulerStepReport:
 class RequestScheduler:
     """Admission queue + slot allocator + decode loop over a tier server.
 
-    ``server`` is any of :class:`~repro.serving.engine.ServingEngine`,
-    :class:`~repro.serving.partitioned.PartitionedServer`,
-    :class:`~repro.serving.multitier.MultiTierServer` — anything exposing
-    ``cfg``, ``executor`` and ``step(tok, pos, caches, active=...)``.
+    ``server`` is a :class:`~repro.serving.engine.ServingEngine` or a
+    :class:`~repro.serving.partitioned.PartitionedServer` — anything
+    exposing ``cfg``, ``executor`` and ``step(tok, pos, caches,
+    active=...)`` returning a report whose ``tier_result`` is the
+    executor's step result.
     Servers construct one lazily behind ``submit()/run()/drain()``; build
     it directly to control ``slots``/``context_len``/``policy``.
 
@@ -353,14 +354,11 @@ class RequestScheduler:
             now = self.clock()
             self.step_count += 1
             self.decode_steps += 1
-            # Servers wrap the executor's TierStepResult in their own report;
-            # the raw result carries the uniform per-slot fields.
-            res = getattr(rep, "tier_result", rep)
+            res = rep.tier_result
             tokens = np.asarray(res.tokens)
             exited = np.asarray(res.exited)
             exit_tier = np.asarray(res.exit_tier)
-            deg_mask = getattr(res, "degraded", None)
-            fail_mask = getattr(res, "failed", None)
+            deg_mask, fail_mask = res.degraded, res.failed
             self.tok_dev = res.tokens_dev[:, None]
 
             emitted: dict[int, int] = {}

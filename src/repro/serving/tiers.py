@@ -3,10 +3,10 @@
 One decode step crosses up to K tiers (device -> edge -> ... -> cloud).
 Every tier runs a contiguous trunk segment, evaluates the side branches
 that live strictly inside it, and ships survivors across its uplink.  The
-monolithic :class:`~repro.serving.engine.ServingEngine` (K=1), the paper's
-:class:`~repro.serving.partitioned.PartitionedServer` (K=2) and the
-beyond-paper :class:`~repro.serving.multitier.MultiTierServer` (K>=3) are
-all thin configurations of the same :class:`TierExecutor`.
+monolithic :class:`~repro.serving.engine.ServingEngine` (K=1) and
+:class:`~repro.serving.partitioned.PartitionedServer` (the paper's K=2
+and any K >= 3 plan) are thin configurations of the same
+:class:`TierExecutor`.
 
 Branch placement follows the paper's semantics (Sec. IV-B, Fig. 2(c)):
 
@@ -398,6 +398,7 @@ from repro.models.model import (
 __all__ = [
     "TierSegment",
     "TierStepResult",
+    "StepReport",
     "TierExecutor",
     "HopCompaction",
     "segments_for_cuts",
@@ -563,6 +564,26 @@ class TierStepResult:
     failed: np.ndarray | None = None
     fault_events: tuple[FaultEvent, ...] = ()
     degraded_hop: int | None = None
+
+
+@dataclasses.dataclass
+class StepReport:
+    """One decode step of a server (:class:`~repro.serving.partitioned.
+    PartitionedServer`, :class:`~repro.serving.engine.ServingEngine`): the
+    executor's result and what the server adds."""
+
+    tier_result: TierStepResult
+    #: The plan's cost model at the installed cuts with the step's measured
+    #: exit fractions (None without a ``cost_profile``).
+    est_latency_s: float | None = None
+    #: bytes * 8 / uplink_bps per executed hop (0.0 for an unset uplink).
+    transfer_s_per_hop: tuple[float, ...] = ()
+    # Cumulative executor health counters (bucket-policy observability):
+    # steps re-run on bucket overflow, and pipelined steps that fell back
+    # to serial because of one.
+    overflow_retries: int = 0
+    pipeline_fallbacks: int = 0
+
 
 
 class TierExecutor:

@@ -21,7 +21,13 @@ import numpy as np
 import pytest
 
 from repro.configs import get_smoke_config
-from repro.core import LayerCost, NetworkProfile, build_cost_profile
+from repro.core import (
+    BranchSpec,
+    CostProfile,
+    LayerCost,
+    NetworkProfile,
+    build_cost_profile,
+)
 from repro.core.multitier import (
     TierSpec,
     bucket_for,
@@ -31,7 +37,6 @@ from repro.core.multitier import (
 )
 from repro.models import model as M
 from repro.serving import (
-    MultiTierServer,
     PartitionedServer,
     RepartitionController,
     TierExecutor,
@@ -367,9 +372,10 @@ class TestSimulatedNetwork:
         t0 = time.perf_counter()
         rep, caches = srv.step(tok, 1, caches)
         dt = time.perf_counter() - t0
-        expected = rep.bytes_shipped * 8.0 / bw
-        assert rep.sim_transfer_s == (pytest.approx(expected),)
-        if rep.shipped:
+        res = rep.tier_result
+        expected = res.bytes_per_hop[0] * 8.0 / bw
+        assert res.sim_transfer_s == (pytest.approx(expected),)
+        if res.shipped_per_hop[0]:
             assert dt >= 0.9 * expected
 
     def test_no_simulation_by_default(self, deep_model):
@@ -377,7 +383,7 @@ class TestSimulatedNetwork:
         srv = PartitionedServer(cfg, params, 2,
                                 network=NetworkProfile("fast", 1e9))
         rep, _ = srv.step(_toks(cfg, 4), 0, M.init_caches(cfg, 4, 32))
-        assert rep.sim_transfer_s == ()
+        assert rep.tier_result.sim_transfer_s == ()
 
 
 class TestDriftController:
@@ -439,7 +445,7 @@ class TestDriftController:
         ctl._install(np.array([0.2, 0.2]))
         cuts = ctl.update_network(NetworkProfile("3g", 0.4e6))
         assert len(cuts) == 1 and 0 <= cuts[0] <= cfg.num_layers
-        assert srv.network.bandwidth_bps == 0.4e6
+        assert srv.tiers[0].uplink_bps == 0.4e6
         # The executor's installed segments carry the new uplink.
         edge = srv.executor.segments[0]
         if not edge.is_empty and len(srv.executor.segments) > 1:
@@ -450,8 +456,8 @@ class TestDriftController:
         profile = self._profile(cfg, np.array([0.2, 0.2]))
         tiers = [TierSpec("d", 50.0, 1e6), TierSpec("e", 10.0, 1e7),
                  TierSpec("c", 1.0)]
-        srv = MultiTierServer(cfg, params, tiers, (1, 2),
-                              cost=(profile.t_c, profile.alpha))
+        srv = PartitionedServer(cfg, params, (1, 2), tiers=tiers,
+                                cost_profile=profile)
         ctl = RepartitionController(srv, profile, tiers, batch=8)
         new_tiers = [TierSpec("d", 50.0, 5e5), TierSpec("e", 10.0, 5e6),
                      TierSpec("c", 1.0)]
@@ -459,7 +465,7 @@ class TestDriftController:
         assert len(cuts) == 2 and cuts[0] <= cuts[1] <= cfg.num_layers
         assert srv.tiers[0].uplink_bps == 5e5
         rep, _ = srv.step(_toks(cfg, 4), 0, M.init_caches(cfg, 4, 32))
-        assert rep.tokens.shape == (4,)
+        assert rep.tier_result.tokens.shape == (4,)
 
 
 class TestPipelinedCompaction:
@@ -592,24 +598,29 @@ class TestServerPlumbing:
         caches = M.init_caches(cfg, 4, 32)
         tok = _toks(cfg, 4)
         rep, caches = srv.step(tok, 0, caches)
-        rep, caches = srv.step(tok, 1, caches)
-        assert rep.compaction[0].survivors == 0
-        assert rep.compaction[0].bucket == 1
-        assert rep.compaction[0].padded_waste == 1
-        assert set(rep.branch_take) == {1}
+        res = srv.step(tok, 1, caches)[0].tier_result
+        assert res.compaction[0].survivors == 0
+        assert res.compaction[0].bucket == 1
+        assert res.compaction[0].padded_waste == 1
+        assert set(res.branch_take) == {1}
 
     def test_multitier_bucketed_estimate_counts_padding(self, deep_model):
         """With compaction on and exits live, the report's estimate uses
         the bucketed cost model (>= the ideal per-sample estimate)."""
         cfg0, params = deep_model
         cfg = dataclasses.replace(cfg0, exit_threshold=1.5)
-        profile_tc = np.concatenate([[0.0], np.full(cfg.num_layers, 1e-3)])
-        alpha = np.full(cfg.num_layers + 1, cfg.d_model * 2.0)
+        profile = CostProfile(
+            t_c=np.concatenate([[0.0], np.full(cfg.num_layers, 1e-3)]),
+            alpha=np.full(cfg.num_layers + 1, cfg.d_model * 2.0),
+            branches=tuple(BranchSpec(b, 0.0) for b in cfg.branch_layers),
+            gamma=25.0,
+            network=NetworkProfile("e", 1e7),
+        )
         tiers = [TierSpec("e", 25.0, 1e7), TierSpec("c", 1.0)]
-        on = MultiTierServer(cfg, params, tiers, (2,),
-                             cost=(profile_tc, alpha))
-        off = MultiTierServer(cfg, params, tiers, (2,),
-                              cost=(profile_tc, alpha), compaction="off")
+        on = PartitionedServer(cfg, params, (2,), tiers=tiers,
+                               cost_profile=profile)
+        off = PartitionedServer(cfg, params, (2,), tiers=tiers,
+                                cost_profile=profile, compaction="off")
         caches = M.init_caches(cfg, 8, 32)
         rep_on, _ = on.step(_toks(cfg, 8), 0, M.init_caches(cfg, 8, 32))
         rep_off, _ = off.step(_toks(cfg, 8), 0, caches)

@@ -40,7 +40,7 @@ from repro.core import LayerCost, build_cost_profile
 from repro.core.multitier import TierSpec, _hop_seconds, solve_multitier
 from repro.models import model as M
 from repro.serving import (
-    MultiTierServer,
+    PartitionedServer,
     RepartitionController,
     RequestScheduler,
     TierExecutor,
@@ -435,8 +435,8 @@ def _fault_server(cfg, params, fm, hp, *, tiers=None, cuts=(1, 3), slots=4):
         TierSpec("mid", 2.0, 1e9),
         TierSpec("cloud", 1.0),
     ]
-    return MultiTierServer(
-        cfg, params, tiers, cuts, simulate_network=True,
+    return PartitionedServer(
+        cfg, params, cuts, tiers=tiers, simulate_network=True,
         slots=slots, context_len=64, fault_model=fm, hop_policy=hp,
     )
 
@@ -549,8 +549,8 @@ class TestControllerHopHealth:
             TierSpec("mid", 2.0, 1e9),
             TierSpec("cloud", 1.0),
         ]
-        srv = MultiTierServer(cfg, params, tiers, (1, 3), slots=4,
-                              context_len=64)
+        srv = PartitionedServer(cfg, params, (1, 3), tiers=tiers, slots=4,
+                                context_len=64)
         return RepartitionController(srv, _profile(cfg), tiers=tiers, **kw), srv
 
     @staticmethod

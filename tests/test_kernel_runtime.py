@@ -25,7 +25,6 @@ from repro.core import LayerCost, NetworkProfile, build_cost_profile
 from repro.core.multitier import TierSpec, bucket_for
 from repro.models import model as M
 from repro.serving import (
-    MultiTierServer,
     PartitionedServer,
     RepartitionController,
     TierExecutor,
@@ -221,7 +220,7 @@ class TestReportCounters:
         rep, caches = srv.step(_toks(cfg, 8), 0, caches)
         assert rep.overflow_retries == 0 and rep.pipeline_fallbacks == 0
         srv.executor._hints = {1: 1}  # stale all-exit hint: 8 arrive
-        rep, caches = srv.step(rep.tokens[:, None], 1, caches)
+        rep, caches = srv.step(rep.tier_result.tokens[:, None], 1, caches)
         assert rep.overflow_retries == 1
 
     def test_multitier_server_surfaces_counters(self, deep_model):
@@ -229,7 +228,7 @@ class TestReportCounters:
         cfg = dataclasses.replace(cfg0, exit_threshold=0.0)
         tiers = [TierSpec("edge", 2.0, 1e9), TierSpec("mid", 1.5, 1e9),
                  TierSpec("cloud", 1.0)]
-        srv = MultiTierServer(cfg, params, tiers, (1, 3))
+        srv = PartitionedServer(cfg, params, (1, 3), tiers=tiers)
         caches = M.init_caches(cfg, 4, 32)
         rep, caches = srv.step(_toks(cfg, 4), 0, caches)
         assert rep.overflow_retries == 0 and rep.pipeline_fallbacks == 0
@@ -294,9 +293,10 @@ class TestProbeSteps:
         saw_probe = False
         for _ in range(4):
             rep, caches = srv.step(tok, pos, caches)
-            saw_probe |= 3 in rep.branch_take
-            ctl.observe(rep)
-            tok, pos = rep.tokens[:, None], pos + 1
+            res = rep.tier_result
+            saw_probe |= 3 in res.branch_take
+            ctl.observe(res)
+            tok, pos = res.tokens[:, None], pos + 1
         assert saw_probe  # the schedule really probed
         assert ctl._arrivals[1] > 0  # discarded branch observed arrivals
         measured = ctl.measured_probs()
@@ -355,6 +355,6 @@ class TestProbeSteps:
         ctl._installed_p = np.array([0.3, 0.7])
         caches = M.init_caches(cfg, 8, 32)
         rep, caches = srv.step(_toks(cfg, 8), 0, caches)
-        ctl.observe(rep)
+        ctl.observe(rep.tier_result)
         assert ctl._arrivals[1] == 0  # branch 3 never evaluated
         assert ctl.measured_probs()[1] == pytest.approx(0.7)  # carried

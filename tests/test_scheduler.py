@@ -37,7 +37,6 @@ from repro.core.multitier import (
 )
 from repro.models import model as M
 from repro.serving import (
-    MultiTierServer,
     PartitionedServer,
     RepartitionController,
     RequestScheduler,
@@ -105,8 +104,8 @@ def _server(cfg, params, cuts, *, compaction="bucketed", use_kernels=None,
         )
     tiers = [TierSpec(f"t{j}", 1.0, 1e9) for j in range(len(cuts))]
     tiers.append(TierSpec("cloud", 1.0))
-    return MultiTierServer(
-        cfg, params, tiers, cuts, compaction=compaction,
+    return PartitionedServer(
+        cfg, params, cuts, tiers=tiers, compaction=compaction,
         use_kernels=use_kernels, slots=slots, context_len=context_len,
     )
 
@@ -483,11 +482,11 @@ class TestOccupancyCost:
         for p in _prompts(cfg, 2, 4):
             sched.submit(p, 6)
         half = sched.step().server_report
-        assert half.live == 2
+        assert half.tier_result.live == 2
         for p in _prompts(cfg, 2, 4, seed=8):
             sched.submit(p, 6)
         full = sched.step().server_report
-        assert full.live == 4
+        assert full.tier_result.live == 4
         assert half.est_latency_s <= full.est_latency_s
 
     def test_controller_tracks_occupancy(self, deep_model):

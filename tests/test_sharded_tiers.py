@@ -37,7 +37,7 @@ from repro.core.multitier import (
 )
 from repro.launch.mesh import make_local_mesh, mesh_devices
 from repro.models import model as M
-from repro.serving import MultiTierServer, PartitionedServer
+from repro.serving import PartitionedServer
 
 multi_device = pytest.mark.skipif(
     jax.device_count() < 2,
@@ -78,13 +78,10 @@ def _trajectory(srv, cfg, steps=4, batch=4):
     out = []
     for i in range(steps):
         rep, caches = srv.step(tok, i, caches)
-        exited = getattr(rep, "exited", getattr(rep, "exited_on_edge", None))
-        shipped = getattr(
-            rep, "shipped_per_hop", (getattr(rep, "shipped", 0),)
-        )
-        out.append((rep.tokens.copy(), np.asarray(exited).copy(),
-                    tuple(shipped)))
-        tok = jnp.asarray(rep.tokens[:, None])
+        res = rep.tier_result
+        out.append((res.tokens.copy(), np.asarray(res.exited).copy(),
+                    res.shipped_per_hop))
+        tok = jnp.asarray(res.tokens[:, None])
     return out
 
 
@@ -110,7 +107,7 @@ class TestShardedEquivalence:
             cfg, params, 2, compaction=compaction, mesh=make_local_mesh()
         )
         assert srv.executor.sharded
-        assert srv.tier_devices == (1, jax.device_count())
+        assert tuple(t.devices for t in srv.tiers) == (1, jax.device_count())
         _assert_same_trajectory(ref, _trajectory(srv, cfg))
 
     @pytest.mark.parametrize("compaction", ["bucketed", "off"])
@@ -122,11 +119,11 @@ class TestShardedEquivalence:
             TierSpec("cloud", 1.0, devices=jax.device_count(), ici_bps=1e11),
         ]
         ref = _trajectory(
-            MultiTierServer(cfg, params, tiers, (1, 3),
-                            compaction=compaction), cfg
+            PartitionedServer(cfg, params, (1, 3), tiers=tiers,
+                              compaction=compaction), cfg
         )
-        srv = MultiTierServer(
-            cfg, params, tiers, (1, 3), compaction=compaction,
+        srv = PartitionedServer(
+            cfg, params, (1, 3), tiers=tiers, compaction=compaction,
             mesh=make_local_mesh(),
         )
         assert srv.executor.sharded
@@ -156,15 +153,15 @@ class TestShardedEquivalence:
         tok = _toks(cfg)
         for i in range(4):
             rep, caches = srv.step(tok, i, caches)
-            tok = jnp.asarray(rep.tokens[:, None])
+            tok = jnp.asarray(rep.tier_result.tokens[:, None])
         assert srv.executor.host_syncs == 4
 
     def test_hot_swap_keeps_sharded_segment_fns(self, moe_model):
         cfg, params = moe_model
         tiers = [TierSpec("d", 100.0, 1e6), TierSpec("e", 10.0, 1e7),
                  TierSpec("c", 1.0)]
-        srv = MultiTierServer(
-            cfg, params, tiers, (1, 3), mesh=make_local_mesh()
+        srv = PartitionedServer(
+            cfg, params, (1, 3), tiers=tiers, mesh=make_local_mesh()
         )
         cloud_fn = srv.executor.segment_fn(2)
         srv.install_cuts((2, 3))  # move only the first cut

@@ -40,16 +40,16 @@ class TestPartitionedEquivalence:
         mono = M.decode_step(params, tok, jnp.asarray(0, jnp.int32), caches0, cfg)
 
         # Partitioned at layer 1.
-        srv = PartitionedServer(cfg, params, split_layer=1)
+        srv = PartitionedServer(cfg, params, 1)
         caches1 = M.init_caches(cfg, batch, ctx)
-        rep, _ = srv.step(tok, 0, caches1)
+        res = srv.step(tok, 0, caches1)[0].tier_result
 
         mono_tok = np.asarray(jnp.argmax(mono["logits"], -1))
         # Sequences that exited on the edge emit branch tokens; everything
         # that crossed the cut must match the monolithic forward exactly.
-        crossed = ~rep.exited_on_edge
+        crossed = ~res.exited
         assert crossed.any()
-        np.testing.assert_array_equal(rep.tokens[crossed], mono_tok[crossed])
+        np.testing.assert_array_equal(res.tokens[crossed], mono_tok[crossed])
 
     def test_edge_only_and_cloud_only_bytes(self, small_model):
         cfg, params = small_model
@@ -58,12 +58,12 @@ class TestPartitionedEquivalence:
         tok = jnp.zeros((batch, 1), jnp.int32)
 
         srv0 = PartitionedServer(cfg, params, 0)
-        rep0, _ = srv0.step(tok, 0, M.init_caches(cfg, batch, 32))
-        assert rep0.shipped == batch  # everything goes to the cloud
+        res0 = srv0.step(tok, 0, M.init_caches(cfg, batch, 32))[0].tier_result
+        assert res0.shipped_per_hop == (batch,)  # everything goes to the cloud
 
         srvN = PartitionedServer(cfg, params, total)
-        repN, _ = srvN.step(tok, 0, M.init_caches(cfg, batch, 32))
-        assert repN.shipped == 0 and repN.bytes_shipped == 0.0
+        resN = srvN.step(tok, 0, M.init_caches(cfg, batch, 32))[0].tier_result
+        assert sum(resN.shipped_per_hop) == 0 and sum(resN.bytes_per_hop) == 0.0
 
 
 class TestCalibrationLoop:

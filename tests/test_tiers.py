@@ -1,10 +1,12 @@
 """Unified K-tier runtime (serving/tiers.py): segment planning rules,
-tier-count equivalences (K=1 engine vs monolithic decode, K=2 MultiTier vs
-PartitionedServer), single-host-sync invariant, per-hop byte accounting,
-the repartition controller's no-re-jit hot swap, the pipelined overlap
-mode (bitwise equivalence + bottleneck cost model + plan flip), and the
-latency-estimator regressions (per-branch conditional probs, zero-uplink
-transfer guard, degenerate-profile solver diagnostic)."""
+tier-count equivalences (K=1 engine vs monolithic decode, a K=2 plan
+from a NetworkProfile vs one from two TierSpecs), single-host-sync
+invariant, per-hop byte accounting, the repartition controller's
+no-re-jit hot swap, the one solve rule (``paper_route``) across K=2
+regimes, the pipelined overlap mode (bitwise equivalence + bottleneck
+cost model + plan flip), and the latency-estimator regressions
+(per-branch conditional probs, zero-uplink transfer guard,
+degenerate-profile solver diagnostic)."""
 
 import dataclasses
 import types
@@ -27,13 +29,13 @@ from repro.core.latency import expected_time
 from repro.core.multitier import TierSpec, expected_time_multitier, solve_multitier
 from repro.models import model as M
 from repro.serving import (
-    MultiTierServer,
     PartitionedServer,
     RepartitionController,
     ServingEngine,
     TierExecutor,
     segments_for_cuts,
 )
+from repro.serving.partitioned import paper_route
 
 
 @pytest.fixture(scope="module")
@@ -256,34 +258,42 @@ class TestTierEquivalence:
 
     @pytest.mark.parametrize("split", [0, 1, 2, 3, 4])
     def test_multitier_k2_matches_partitioned(self, deep_model, split):
+        """A K=2 plan derived from a NetworkProfile and one given as two
+        TierSpecs run the same step: tokens, masks, bytes and cuts."""
         cfg, params = deep_model
         tok = _toks(cfg)
-        p2 = PartitionedServer(cfg, params, split)
-        k2 = MultiTierServer(
-            cfg, params, [TierSpec("edge", 25.0, 5.85e6), TierSpec("cloud", 1.0)],
-            (split,),
+        p2 = PartitionedServer(cfg, params, split,
+                               network=NetworkProfile("4g", 5.85e6))
+        k2 = PartitionedServer(
+            cfg, params, (split,),
+            tiers=[TierSpec("edge", 25.0, 5.85e6), TierSpec("cloud", 1.0)],
         )
-        rep, _ = p2.step(tok, 0, M.init_caches(cfg, 4, 32))
-        mrep, _ = k2.step(tok, 0, M.init_caches(cfg, 4, 32))
-        np.testing.assert_array_equal(mrep.tokens, rep.tokens)
-        np.testing.assert_array_equal(mrep.exited, rep.exited_on_edge)
-        assert sum(mrep.shipped_per_hop) == rep.shipped
-        assert sum(mrep.bytes_per_hop) == rep.bytes_shipped
+        assert p2.cuts == k2.cuts == (split,)
+        assert p2.executor.segments == k2.executor.segments
+        res = p2.step(tok, 0, M.init_caches(cfg, 4, 32))[0].tier_result
+        mres = k2.step(tok, 0, M.init_caches(cfg, 4, 32))[0].tier_result
+        np.testing.assert_array_equal(mres.tokens, res.tokens)
+        np.testing.assert_array_equal(mres.exited, res.exited)
+        assert mres.shipped_per_hop == res.shipped_per_hop
+        assert mres.bytes_per_hop == res.bytes_per_hop
 
     def test_multitier_k2_matches_partitioned_with_exits(self, deep_model):
         cfg, params = deep_model
         cfg = dataclasses.replace(cfg, exit_threshold=1.5)  # everyone exits
         tok = _toks(cfg)
-        p2 = PartitionedServer(cfg, params, 2)
-        k2 = MultiTierServer(
-            cfg, params, [TierSpec("e", 25.0, 1e7), TierSpec("c", 1.0)], (2,)
+        p2 = PartitionedServer(cfg, params, 2, network=NetworkProfile("e", 1e7))
+        k2 = PartitionedServer(
+            cfg, params, (2,),
+            tiers=[TierSpec("edge", 25.0, 1e7), TierSpec("cloud", 1.0)],
         )
-        rep, _ = p2.step(tok, 0, M.init_caches(cfg, 4, 32))
-        mrep, _ = k2.step(tok, 0, M.init_caches(cfg, 4, 32))
-        assert rep.exited_on_edge.all() and mrep.exited.all()
-        np.testing.assert_array_equal(mrep.tokens, rep.tokens)
-        assert mrep.shipped_per_hop == (0,) and rep.shipped == 0
-        assert (mrep.exit_tier == 0).all()
+        assert p2.cuts == k2.cuts == (2,)
+        res = p2.step(tok, 0, M.init_caches(cfg, 4, 32))[0].tier_result
+        mres = k2.step(tok, 0, M.init_caches(cfg, 4, 32))[0].tier_result
+        assert res.exited.all() and mres.exited.all()
+        np.testing.assert_array_equal(mres.tokens, res.tokens)
+        assert mres.shipped_per_hop == res.shipped_per_hop == (0,)
+        assert mres.bytes_per_hop == res.bytes_per_hop
+        assert (mres.exit_tier == 0).all() and (res.exit_tier == 0).all()
 
     def test_k3_survivors_match_monolithic_tokens(self, deep_model):
         cfg, params = deep_model
@@ -292,15 +302,14 @@ class TestTierEquivalence:
         mono = M.decode_step(params, tok, jnp.asarray(0, jnp.int32), caches, cfg)
         mono_tok = np.asarray(jnp.argmax(mono["logits"], -1))
 
-        srv = MultiTierServer(
-            cfg, params,
-            [TierSpec("device", 200.0, 1e6), TierSpec("edge", 20.0, 2e7),
-             TierSpec("cloud", 1.0)],
-            (2, 3),
+        srv = PartitionedServer(
+            cfg, params, (2, 3),
+            tiers=[TierSpec("device", 200.0, 1e6), TierSpec("edge", 20.0, 2e7),
+                   TierSpec("cloud", 1.0)],
         )
-        rep, _ = srv.step(tok, 0, M.init_caches(cfg, 4, 32))
-        crossed = ~rep.exited
-        np.testing.assert_array_equal(rep.tokens[crossed], mono_tok[crossed])
+        res = srv.step(tok, 0, M.init_caches(cfg, 4, 32))[0].tier_result
+        crossed = ~res.exited
+        np.testing.assert_array_equal(res.tokens[crossed], mono_tok[crossed])
 
     def test_engine_matches_legacy_decode_loop(self, deep_model):
         """The fused device-resident exit masking reproduces the old
@@ -345,7 +354,7 @@ class TestHostSyncs:
         tok = _toks(cfg)
         for i in range(4):
             rep, caches = srv.step(tok, i, caches)
-            tok = jnp.asarray(rep.tokens[:, None])
+            tok = jnp.asarray(rep.tier_result.tokens[:, None])
         assert srv.executor.host_syncs == 4
 
     def test_one_sync_per_engine_step(self, deep_model):
@@ -362,40 +371,40 @@ class TestByteAccounting:
         cfg, params = deep_model
         tiers = [TierSpec("d", 100.0, 1e6), TierSpec("e", 10.0, 1e7),
                  TierSpec("c", 1.0)]
-        srv = MultiTierServer(cfg, params, tiers, (2, 3))
+        srv = PartitionedServer(cfg, params, (2, 3), tiers=tiers)
         rep, _ = srv.step(_toks(cfg), 0, M.init_caches(cfg, 4, 32))
-        assert len(rep.shipped_per_hop) == 2
+        res = rep.tier_result
+        assert len(res.shipped_per_hop) == 2
         per_seq = cfg.d_model * 2.0
-        assert rep.bytes_per_hop == (
-            rep.shipped_per_hop[0] * per_seq, rep.shipped_per_hop[1] * per_seq
+        assert res.bytes_per_hop == (
+            res.shipped_per_hop[0] * per_seq, res.shipped_per_hop[1] * per_seq
         )
         # Survivors never resurrect across hops.
-        assert rep.shipped_per_hop[0] >= rep.shipped_per_hop[1]
+        assert res.shipped_per_hop[0] >= res.shipped_per_hop[1]
         # Transfer time is bytes over the *hop's own* uplink.
         assert rep.transfer_s_per_hop == (
-            rep.bytes_per_hop[0] * 8.0 / tiers[0].uplink_bps,
-            rep.bytes_per_hop[1] * 8.0 / tiers[1].uplink_bps,
+            res.bytes_per_hop[0] * 8.0 / tiers[0].uplink_bps,
+            res.bytes_per_hop[1] * 8.0 / tiers[1].uplink_bps,
         )
 
     def test_cloud_only_ships_token_ids(self, deep_model):
         cfg, params = deep_model
-        srv = MultiTierServer(
-            cfg, params, [TierSpec("e", 25.0, 1e7), TierSpec("c", 1.0)], (0,)
+        srv = PartitionedServer(
+            cfg, params, (0,), tiers=[TierSpec("e", 25.0, 1e7), TierSpec("c", 1.0)]
         )
-        rep, _ = srv.step(_toks(cfg), 0, M.init_caches(cfg, 4, 32))
-        assert rep.shipped_per_hop == (4,)
-        assert rep.bytes_per_hop == (16.0,)  # 4 sequences * 4-byte token id
+        res = srv.step(_toks(cfg), 0, M.init_caches(cfg, 4, 32))[0].tier_result
+        assert res.shipped_per_hop == (4,)
+        assert res.bytes_per_hop == (16.0,)  # 4 sequences * 4-byte token id
 
     def test_trailing_empty_tiers_ship_nothing(self, deep_model):
         cfg, params = deep_model
-        srv = MultiTierServer(
-            cfg, params,
-            [TierSpec("d", 100.0, 1e6), TierSpec("e", 10.0, 1e7),
-             TierSpec("c", 1.0)],
-            (4, 4),
+        srv = PartitionedServer(
+            cfg, params, (4, 4),
+            tiers=[TierSpec("d", 100.0, 1e6), TierSpec("e", 10.0, 1e7),
+                   TierSpec("c", 1.0)],
         )
-        rep, _ = srv.step(_toks(cfg), 0, M.init_caches(cfg, 4, 32))
-        assert rep.shipped_per_hop == () and rep.bytes_per_hop == ()
+        res = srv.step(_toks(cfg), 0, M.init_caches(cfg, 4, 32))[0].tier_result
+        assert res.shipped_per_hop == () and res.bytes_per_hop == ()
 
 
 class TestPipelinedRuntime:
@@ -479,9 +488,10 @@ class TestEstimatorRegressions:
         take3 = np.zeros(8, bool)
         take3[4:6] = True  # 2 of the 4 still alive after branch 1
         res = types.SimpleNamespace(
-            tokens=np.zeros(8, np.int64), branch_take={1: take1, 3: take3}
+            tokens=np.zeros(8, np.int64), branch_take={1: take1, 3: take3},
+            live=8,
         )
-        est = srv._estimate(4, res)
+        est = srv._estimate(res)
 
         def at_probs(p1, p3):
             branches = tuple(
@@ -504,22 +514,21 @@ class TestEstimatorRegressions:
         was never set must report 0.0 transfer time, not ZeroDivisionError
         (mirrors the executor's sim_transfer_s guard)."""
         cfg, params = deep_model
-        srv = MultiTierServer(
-            cfg, params, [TierSpec("e", 25.0), TierSpec("c", 1.0)], (2,)
+        srv = PartitionedServer(
+            cfg, params, (2,), tiers=[TierSpec("e", 25.0), TierSpec("c", 1.0)]
         )
         rep, _ = srv.step(_toks(cfg), 0, M.init_caches(cfg, 4, 32))
         assert rep.transfer_s_per_hop == (0.0,)
-        assert rep.tokens.shape == (4,)
+        assert rep.tier_result.tokens.shape == (4,)
 
 
 class TestRepartition:
     def test_swap_reuses_unchanged_segments(self, deep_model):
         cfg, params = deep_model
-        srv = MultiTierServer(
-            cfg, params,
-            [TierSpec("d", 100.0, 1e6), TierSpec("e", 10.0, 1e7),
-             TierSpec("c", 1.0)],
-            (1, 3),
+        srv = PartitionedServer(
+            cfg, params, (1, 3),
+            tiers=[TierSpec("d", 100.0, 1e6), TierSpec("e", 10.0, 1e7),
+                   TierSpec("c", 1.0)],
         )
         cloud_fn = srv.executor.segment_fn(2)
         srv.install_cuts((2, 3))  # move only the first cut
@@ -547,9 +556,9 @@ class TestRepartition:
         ctl = RepartitionController(srv, profile)
         cuts = ctl.update(stats)
         assert len(cuts) == 1 and 0 <= cuts[0] <= cfg.num_layers
-        assert srv.split_layer == cuts[0]
+        assert srv.cuts == cuts
         rep, _ = srv.step(_toks(cfg), 0, M.init_caches(cfg, 4, 32))
-        assert rep.tokens.shape == (4,)
+        assert rep.tier_result.tokens.shape == (4,)
 
     def test_controller_solves_overlap_for_pipelined_server(self, deep_model):
         """A pipelined server is re-solved against the bottleneck-stage
@@ -586,7 +595,7 @@ class TestRepartition:
         )
         assert cut == best
         ctl._install(p_k)
-        assert srv.split_layer == cut
+        assert srv.cuts == (cut,)
 
     def test_controller_bucketed_2tier_solves_lattice_objective(self, deep_model):
         """With batch set and a compacting 2-tier server, solve() optimizes
@@ -638,17 +647,153 @@ class TestRepartition:
         )
         tiers = [TierSpec("d", 50.0, 1e6), TierSpec("e", 10.0, 1e7),
                  TierSpec("c", 1.0)]
-        srv = MultiTierServer(cfg, params, tiers, (0, 0),
-                              cost=(profile.t_c, profile.alpha))
+        srv = PartitionedServer(cfg, params, (0, 0), tiers=tiers,
+                                cost_profile=profile)
         ctl = RepartitionController(srv, profile, tiers)
         cuts = ctl.update(stats)
         assert len(cuts) == 2 and cuts[0] <= cuts[1] <= cfg.num_layers
         rep, _ = srv.step(_toks(cfg), 0, M.init_caches(cfg, 4, 32))
         assert rep.est_latency_s is not None and rep.est_latency_s > 0
-        if not rep.exited.any():
+        if not rep.tier_result.exited.any():
             # No live exits -> the report's estimate is exactly the lattice
             # cost model at p == 0.
             zero_p = np.zeros(cfg.num_layers + 1)
             assert rep.est_latency_s == pytest.approx(expected_time_multitier(
                 profile.t_c, profile.alpha, zero_p, tiers, srv.cuts
             ))
+
+
+class TestOneSolveRule:
+    """``paper_route`` is the one rule that picks the cost model for the
+    server's estimate and the controller's solve, from what the plan is."""
+
+    @pytest.mark.parametrize(
+        "k, overlap, batch, availability, paper",
+        [
+            (2, False, None, 1.0, True),  # the paper's Eq. 5 / Dijkstra
+            (2, True, None, 1.0, False),  # pipelined: bottleneck stage
+            (2, False, 8, 1.0, False),  # bucketed with a batch
+            (2, False, None, 0.5, False),  # a degraded hop
+            (3, False, None, 1.0, False),  # K >= 3 is lattice-only
+        ],
+    )
+    def test_route_is_a_function_of_the_plan(
+        self, k, overlap, batch, availability, paper
+    ):
+        tiers = [TierSpec(f"t{j}", 2.0, 1e6, availability=availability)
+                 for j in range(k - 1)] + [TierSpec("cloud", 1.0)]
+        assert paper_route(tiers, overlap, batch) is paper
+
+    def test_branch_compute_priced_on_the_paper_route_only(self, deep_model):
+        """Where the two models disagree (a profile pricing branch-head
+        compute), each route keeps its own answer: Eq. 5 with the
+        gamma * t_b edge terms, the lattice without them."""
+        cfg, params = deep_model
+        costs = [LayerCost(f"l{i}", 0, 0, cfg.d_model * 2.0, 1e-3)
+                 for i in range(cfg.num_layers)]
+        branch = [LayerCost("b", 0, 0, 0, 5e-3)] * len(cfg.branch_layers)
+        profile = build_cost_profile(
+            costs, cfg.branch_layers, np.zeros(2), "3g", 50.0, 64.0,
+            branch_costs=branch, include_branch_compute=True,
+        )
+        res = types.SimpleNamespace(
+            tokens=np.zeros(8, np.int64), live=8,
+            branch_take={1: np.zeros(8, bool)},
+        )
+        serial = PartitionedServer(cfg, params, 2, cost_profile=profile,
+                                   compaction="off")
+        bucketed = PartitionedServer(cfg, params, 2, cost_profile=profile)
+        assert serial._estimate(res) == pytest.approx(expected_time(profile, 2))
+        lattice = expected_time_multitier(
+            profile.t_c, profile.alpha, np.zeros(cfg.num_layers + 1),
+            list(bucketed.tiers), (2,), batch=8, occupancy=1.0,
+        )
+        assert bucketed._estimate(res) == pytest.approx(lattice)
+        assert serial._estimate(res) > lattice  # the branch head's cost
+
+    @pytest.mark.parametrize(
+        "regime", ["serial", "pipelined", "bucketed", "breaker_open"]
+    )
+    def test_k2_network_and_tierspec_plans_install_same_cut(
+        self, deep_model, regime
+    ):
+        """A K=2 server built from a NetworkProfile and one built from the
+        equivalent TierSpecs get the same cut from the controller in
+        every regime: the solve depends on the plan, not the constructor."""
+        cfg, params = deep_model
+        costs = [LayerCost(f"l{i}", 0, 0, cfg.d_model * 2.0, 1e-3)
+                 for i in range(cfg.num_layers)]
+        p_k = np.array([0.3, 0.2])
+        profile = build_cost_profile(
+            costs, cfg.branch_layers, p_k, "3g", 50.0, 64.0
+        )
+        kw = {"overlap": "pipelined"} if regime == "pipelined" else {}
+        batch = 8 if regime == "bucketed" else None
+        servers = [
+            PartitionedServer(cfg, params, 0, cost_profile=profile,
+                              network=NetworkProfile("3g", 1.1e6), **kw),
+            PartitionedServer(
+                cfg, params, (0,), cost_profile=profile,
+                tiers=[TierSpec("edge", 50.0, 1.1e6), TierSpec("cloud", 1.0)],
+                **kw,
+            ),
+        ]
+        installed = []
+        for srv in servers:
+            ctl = RepartitionController(srv, profile, batch=batch)
+            if regime == "breaker_open":
+                ctl._installed_p = p_k
+                ctl._hop_open.add(0)
+                installed.append(ctl.apply_hop_health())
+                assert srv.tiers[0].availability == 0.0
+            else:
+                installed.append(ctl._install(p_k))
+            assert srv.cuts == installed[-1]
+        assert installed[0] == installed[1]
+        if regime == "serial":
+            assert installed[0] == (
+                shortest_path_plan(profile).split_layer,
+            )
+        if regime == "breaker_open":
+            assert installed[0] == (cfg.num_layers,)  # nothing crosses
+
+    def test_k3_update_network_refuses(self, deep_model):
+        cfg, params = deep_model
+        costs = [LayerCost(f"l{i}", 0, 0, cfg.d_model * 2.0, 1e-3)
+                 for i in range(cfg.num_layers)]
+        profile = build_cost_profile(
+            costs, cfg.branch_layers, np.zeros(2), "3g", 50.0, 64.0
+        )
+        srv = PartitionedServer(
+            cfg, params, (1, 3),
+            tiers=[TierSpec("d", 50.0, 1e6), TierSpec("e", 10.0, 1e7),
+                   TierSpec("c", 1.0)],
+        )
+        ctl = RepartitionController(srv, profile)
+        with pytest.raises(ValueError, match="K=2 plan.*3 tiers.*update_tiers"):
+            ctl.update_network(NetworkProfile("3g", 0.4e6))
+        assert srv.tiers[0].uplink_bps == 1e6  # nothing changed
+
+    @pytest.mark.parametrize("compaction", ["off", "bucketed"])
+    def test_k2_controller_prices_its_own_profile_link(
+        self, deep_model, compaction
+    ):
+        """A K=2 server built with no network or cost profile (its uplink
+        unset) still solves for the link and gamma of the controller's
+        profile: the installed cut is the paper's Dijkstra on that
+        profile, not the all-edge plan an unset uplink would price."""
+        cfg, params = deep_model
+        costs = [LayerCost(f"l{i}", 0, 0, cfg.d_model * 2.0, 1e-3)
+                 for i in range(cfg.num_layers)]
+        p_k = np.array([0.3, 0.2])
+        profile = build_cost_profile(
+            costs, cfg.branch_layers, p_k, "3g", 50.0, 64.0
+        )
+        srv = PartitionedServer(cfg, params, cfg.num_layers,
+                                compaction=compaction)
+        assert srv.tiers[0].uplink_bps == 0.0
+        ctl = RepartitionController(srv, profile)
+        want = shortest_path_plan(profile).split_layer
+        assert want < cfg.num_layers  # the profile's link is usable
+        assert ctl._install(p_k) == (want,)
+        assert srv.tiers[0].uplink_bps == 0.0  # the server's link is its own

@@ -2,18 +2,7 @@
 # Tier-1 CI entry: run the test suite exactly as ROADMAP.md specifies
 # (tests/test_compaction.py, tests/test_kernel_runtime.py,
 # tests/test_scheduler.py and the runtime/controller suites are part of
-# the default collection), then smoke-run the serving benchmark sweep
-# and the kernel-vs-jnp decode sweep in fast mode so the
-# masked-vs-compacted FLOPs assertion, the 1-sync invariant, the
-# serial-vs-pipelined overlap cell, the continuous-vs-lock-step request
-# cell (Poisson arrivals, recycled KV slots — REPRO_BENCH_FAST runs it;
-# `make bench-requests` selects it alone), every Pallas kernel path
-# (interpret mode off-TPU, identical-trajectory assert inline), and the
-# batched-exit-heads cells (multi-head kernel bitwise vs single-head,
-# plus the heads/probe_step_k5 batched-vs-sequential decode step with
-# its bitwise-trajectory assert) are exercised end to end on every CI
-# pass.  bench_check also appends each bundle's metrics to the
-# BENCH_history.jsonl per-PR trend series.
+# the default collection).
 # A second pytest process then runs the multi-device lane: XLA_FLAGS
 # must create the 8 virtual CPU devices *before jax initializes*, so the
 # sharded-tier equivalence tests (tests/test_sharded_tiers.py — SPMD
@@ -23,7 +12,6 @@
 # does-everything-still-hold-with-devices-visible check (they pass
 # unchanged; only mesh-marked tests actually shard anything).
 # Usage: tools/ci.sh [extra pytest args]
-#   REPRO_CI_BENCH=0 skips the benchmark smokes (pytest only).
 #   REPRO_CI_SHARDED=0 skips the multi-device lane;
 #   REPRO_CI_SHARDED=fast restricts it to tests/test_sharded_tiers.py.
 set -e
@@ -38,11 +26,4 @@ if [ "${REPRO_CI_SHARDED:-1}" != "0" ]; then
     XLA_FLAGS="--xla_force_host_platform_device_count=8${XLA_FLAGS:+ $XLA_FLAGS}" \
         PYTHONPATH=src${PYTHONPATH:+:$PYTHONPATH} \
         python -m pytest -x -q $sharded_targets
-fi
-if [ "${REPRO_CI_BENCH:-1}" != "0" ]; then
-    REPRO_BENCH_FAST=1 PYTHONPATH=src${PYTHONPATH:+:$PYTHONPATH} \
-        python benchmarks/serving_step.py
-    REPRO_BENCH_FAST=1 PYTHONPATH=src${PYTHONPATH:+:$PYTHONPATH} \
-        python benchmarks/kernel_micro.py
-    python tools/bench_check.py BENCH_serving.json BENCH_kernels.json
 fi
